@@ -74,10 +74,8 @@ def main() -> None:
 
     from repro.utils.cache import enable_persistent_cache
 
-    cache_dir = enable_persistent_cache()
-    if cache_dir:
-        print(f"# persistent compilation cache: {cache_dir}",
-              file=sys.stderr)
+    print(f"# persistent compilation cache: {enable_persistent_cache()}",
+          file=sys.stderr)
 
     from benchmarks import bench_kernels, bench_roofline, bench_serving
     from benchmarks import bench_sim, figures

@@ -100,4 +100,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     main()

@@ -12,24 +12,29 @@ it consumes:
     score   = W_m / est[m, tier(m, task)] - est[...] * 1e-6
     out_b   = argmin over servers with tier(m, task) < K-1
 
-so one kernel launch replaces the per-slot chain of dense XLA ops
-(workload reduction, per-task tier derivation, masked argmin) that
-dominates dispatch time on CPU at M >= 10^4.  Compare `wwl_route.py`,
-which scores ALL servers (including the remote tier) against a
-precomputed workload vector: the fused kernel reads the raw policy state
-(q, serving) instead, and masks the remote tier out, because the fleet
-path assigns remote traffic by water-filling rather than per-task argmin
-(B tasks hitting the same remote argmin would pile onto one server —
-see docs/scaling.md).
+so one kernel launch scores the whole (B, M) surface tile by tile: tasks
+on sublanes, servers on lanes.  Per-server inputs arrive transposed,
+one row per tier or level ((K, M) queues and rates, (1, M) serving,
+(D, M) ancestors), so every per-tier value is a static row slice, and the
+tier of each (task, server) pair is derived by an unrolled `jnp.where`
+chain over the levels — the kernel body holds no gather.  Per-task
+results are (B, 1) columns.  Compare `wwl_route.py`, which scores ALL
+servers (including the remote tier) against a precomputed workload
+vector: the fused kernel reads the raw policy state (q, serving) instead,
+and masks the remote tier out, because the fleet path assigns remote
+traffic by water-filling rather than per-task argmin (B tasks hitting the
+same remote argmin would pile onto one server — see docs/scaling.md).
 
 The ``- rate * 1e-6`` term is the same infinitesimal faster-tier
 preference the sequential simulator applies on exact workload ties
 (`core/balanced_pandas.route_one`); tie-breaking among equal scores is
 lowest-server-index (deterministic), as in the other scheduling kernels.
 
-Semantics contract: `ref.fleet_route`.  The XLA realization used for the
-CPU hot loop lives in `sharding/sim.py` (segment-min candidates); it is
-exact against the same oracle (fuzzed in tests/test_fleet_scale.py).
+Semantics contract: `ref.fleet_route`.  `sharding/sim.py` runs this
+kernel on TPU and an XLA segment-min realization elsewhere; both are
+exact against the same oracle and bitwise equal to each other in the
+fleet loop (tests/test_fleet_scale.py), and tests/test_chip_compile.py
+compiles this kernel for a TPU v5e at fleet size.
 """
 
 from __future__ import annotations
@@ -39,24 +44,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LARGE = 3.0e38  # +inf surrogate inside min-accumulators (matches wwl_route)
 
 
-def _fleet_route_kernel(q_ref, serving_ref, rates_ref, anc_ref, locals_ref,
-                        lanc_ref, score_ref, server_ref, tier_ref, *,
+def _fleet_route_kernel(q_ref, rates_ref, serving_ref, anc_ref, tasks_ref,
+                        score_ref, server_ref, tier_ref, *,
                         block_m: int, depth: int):
     """One (task-block, server-block) tile.
 
-    q_ref:       (bm, K)      f32   waiting tasks per (server, tier)
-    serving_ref: (bm,)        i32   class in service (0 idle, 1..K)
-    rates_ref:   (bm, K)      f32   est tier rates slice (K = depth + 2)
-    anc_ref:     (D, bm)      i32   ancestor table slice of this block
-    locals_ref:  (bt, 3)      i32   task local servers
-    lanc_ref:    (bt, D, 3)   i32   ancestor groups of those locals
-    score_ref:   (bt,)        f32   running min private score   (revisited)
-    server_ref:  (bt,)        i32   running argmin server       (revisited)
-    tier_ref:    (bt,)        i32   tier at argmin              (revisited)
+    q_ref:       (K, bm)        f32   waiting tasks per (tier, server)
+    rates_ref:   (K, bm)        f32   est tier rates (K = depth + 2)
+    serving_ref: (1, bm)        i32   class in service (0 idle, 1..K)
+    anc_ref:     (D, bm)        i32   ancestor table slice of this block
+    tasks_ref:   (bt, 3(D+1))   i32   task locals, then their level-l
+                                      ancestor groups, 3 columns per level
+    score_ref:   (bt, 1)        f32   running min private score (revisited)
+    server_ref:  (bt, 1)        i32   running argmin server     (revisited)
+    tier_ref:    (bt, 1)        i32   tier at argmin            (revisited)
     """
     j = pl.program_id(1)
 
@@ -66,48 +72,55 @@ def _fleet_route_kernel(q_ref, serving_ref, rates_ref, anc_ref, locals_ref,
         server_ref[...] = jnp.zeros_like(server_ref)
         tier_ref[...] = jnp.zeros_like(tier_ref)
 
-    q = q_ref[...]                             # (bm, K)
-    rates = rates_ref[...]                     # (bm, K)
-    serving = serving_ref[...]                 # (bm,)
-    locs = locals_ref[...]                     # (bt, 3)
-    k = q.shape[1]
+    q = q_ref[...]
+    rates = rates_ref[...]
+    serving = serving_ref[...]
+    tasks = tasks_ref[...]
+    k = q.shape[0]
+    row = [rates[t:t + 1, :] for t in range(k)]            # K x (1, bm)
 
     # fused workload: left-associative tier sum + in-service residual,
     # matching core/balanced_pandas.workload bit-for-bit
-    w = q[:, 0] / rates[:, 0]
+    w = q[0:1, :] / row[0]
     for t in range(1, k):
-        w = w + q[:, t] / rates[:, t]
+        w = w + q[t:t + 1, :] / row[t]
     resid_idx = jnp.clip(serving - 1, 0, k - 1)
-    resid_rate = jnp.take_along_axis(rates, resid_idx[:, None], axis=1)[:, 0]
+    resid_rate = row[0]
+    for t in range(1, k):
+        resid_rate = jnp.where(resid_idx == t, row[t], resid_rate)
     w = w + jnp.where(serving > 0, 1.0 / resid_rate, 0.0)
 
-    bt = locs.shape[0]
-    bm = w.shape[0]
-    sid = j * block_m + jax.lax.broadcasted_iota(jnp.int32, (bt, bm), 1)
+    bt = tasks.shape[0]
+    bm = w.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (bt, bm), 1)
+    sid = j * block_m + col
 
-    local = (sid == locs[:, 0:1]) | (sid == locs[:, 1:2]) | (sid == locs[:, 2:3])
+    def hits(ids, c):
+        """(bt, bm) mask: ids equal one of the task's columns c..c+2."""
+        return ((ids == tasks[:, c:c + 1]) | (ids == tasks[:, c + 1:c + 2])
+                | (ids == tasks[:, c + 2:c + 3]))
+
     # remote by default; sharpen tier/rate level by level, deepest first —
     # the depth loop is unrolled at trace time (static shape)
     tier = jnp.full((bt, bm), depth + 1, jnp.int32)
-    rate = jnp.broadcast_to(rates[None, :, depth + 1], (bt, bm))
+    rate = jnp.broadcast_to(row[depth + 1], (bt, bm))
     for lvl in range(depth - 1, -1, -1):
-        anc_row = anc_ref[lvl, :]              # (bm,)
-        lanc = lanc_ref[...][:, lvl, :]        # (bt, 3)
-        rk = jnp.broadcast_to(anc_row[None, :], (bt, bm))
-        share = ((rk == lanc[:, 0:1]) | (rk == lanc[:, 1:2])
-                 | (rk == lanc[:, 2:3]))
+        share = hits(anc_ref[lvl:lvl + 1, :], 3 * (lvl + 1))
         tier = jnp.where(share, lvl + 1, tier)
-        rate = jnp.where(share, rates[None, :, lvl + 1], rate)
+        rate = jnp.where(share, row[lvl + 1], rate)
+    local = hits(sid, 0)
     tier = jnp.where(local, 0, tier)
-    rate = jnp.where(local, rates[None, :, 0], rate)
-    score = jnp.broadcast_to(w[None, :], (bt, bm)) / rate - rate * 1e-6
+    rate = jnp.where(local, row[0], rate)
+    score = jnp.broadcast_to(w, (bt, bm)) / rate - rate * 1e-6
     # the private mask: the remote tier (K-1 = depth+1) is pool-filled
     score = jnp.where(tier <= depth, score, LARGE)
 
-    blk_min = jnp.min(score, axis=1)                       # (bt,)
-    blk_arg = jnp.argmin(score, axis=1).astype(jnp.int32)  # (bt,)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)[:, 0]
-    blk_tier = tier[rows, blk_arg]
+    # lowest-index argmin and its tier as masked minima (no gather)
+    blk_min = jnp.min(score, axis=1, keepdims=True)                # (bt, 1)
+    blk_arg = jnp.min(jnp.where(score == blk_min, col, bm), axis=1,
+                      keepdims=True)
+    blk_tier = jnp.min(jnp.where(col == blk_arg, tier, depth + 1), axis=1,
+                       keepdims=True)
 
     best = score_ref[...]
     better = blk_min < best                    # strict: keeps lowest index
@@ -128,39 +141,39 @@ def fleet_route_pallas(q: jnp.ndarray, serving: jnp.ndarray,
     (depth, M) ancestor table.  Caller guarantees M % block_servers == 0
     and B % block_tasks == 0 (ops.fleet_route pads; padding servers carry
     pad ancestor ids that collide only with each other, so they land on
-    the masked remote tier and never win).
+    the masked remote tier and never win).  Returns (server, tier, score),
+    each (B,).
     """
     b = task_locals.shape[0]
-    m = q.shape[0]
+    m, k = q.shape
     depth = server_anc.shape[0]
     grid = (b // block_tasks, m // block_servers)
-    task_lanc = jnp.swapaxes(server_anc[:, task_locals], 0, 1)
+    locs = task_locals.astype(jnp.int32)
+    anc = server_anc.astype(jnp.int32)
+    # (B, 3(D+1)): the locals, then each level's ancestor groups of them
+    tasks = jnp.concatenate(
+        [locs] + [anc[lvl][locs] for lvl in range(depth)], axis=1)
 
     kernel = functools.partial(_fleet_route_kernel, block_m=block_servers,
                                depth=depth)
+    per_server = lambda rows: pl.BlockSpec((rows, block_servers),
+                                           lambda i, j: (0, j))
+    per_task = lambda cols: pl.BlockSpec((block_tasks, cols),
+                                         lambda i, j: (i, 0))
     score, server, tier = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_servers, depth + 2), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_servers,), lambda i, j: (j,)),
-            pl.BlockSpec((block_servers, depth + 2), lambda i, j: (j, 0)),
-            pl.BlockSpec((depth, block_servers), lambda i, j: (0, j)),
-            pl.BlockSpec((block_tasks, 3), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_tasks, depth, 3), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_tasks,), lambda i, j: (i,)),
-            pl.BlockSpec((block_tasks,), lambda i, j: (i,)),
-            pl.BlockSpec((block_tasks,), lambda i, j: (i,)),
-        ],
+        in_specs=[per_server(k), per_server(k), per_server(1),
+                  per_server(depth), per_task(tasks.shape[1])],
+        out_specs=[per_task(1)] * 3,
         out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.float32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q.astype(jnp.float32), serving.astype(jnp.int32),
-      est_rates.astype(jnp.float32), server_anc.astype(jnp.int32),
-      task_locals.astype(jnp.int32), task_lanc.astype(jnp.int32))
-    return server, tier, score
+    )(q.astype(jnp.float32).T, est_rates.astype(jnp.float32).T,
+      serving.astype(jnp.int32)[None, :], anc, tasks)
+    return server[:, 0], tier[:, 0], score[:, 0]

@@ -85,27 +85,6 @@ def _get(cache, key):
 
 # ----------------------------------------------------------------- stage ---
 
-@jax.custom_vjp
-def _pin_gathers(tree):
-    """Identity that blocks XLA's loop-invariant hoisting of FSDP weight
-    all-gathers (see the pin_gathers comment below).  `lax.optimization_
-    barrier` has no autodiff rule (NotImplementedError under grad as of jax
-    0.4.37), so this wrapper supplies the obvious one: barrier on the
-    forward, barrier on the (equally hoistable) cotangent gathers."""
-    return jax.lax.optimization_barrier(tree)
-
-
-def _pin_gathers_fwd(tree):
-    return jax.lax.optimization_barrier(tree), None
-
-
-def _pin_gathers_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-_pin_gathers.defvjp(_pin_gathers_fwd, _pin_gathers_bwd)
-
-
 def _stage_forward(sp: Dict[str, Any], cfg: ModelConfig, stage: Stage,
                    x: jnp.ndarray, positions: jnp.ndarray,
                    cache: Optional[Dict[str, Any]],
@@ -121,7 +100,7 @@ def _stage_forward(sp: Dict[str, Any], cfg: ModelConfig, stage: Stage,
             # hoists loop-invariant gathers out of the (microbatch x layer)
             # scans and materializes EVERY layer's gathered weights at once
             # (~49 GB/device for jamba-398B; see EXPERIMENTS.md §Perf P8).
-            layer_p = _pin_gathers(layer_p)
+            layer_p = jax.lax.optimization_barrier(layer_p)
         new_cache: Dict[str, Any] = {}
         for i, spec in enumerate(stage.block):
             sub_cache = (layer_cache.get(f"sub{i}")

@@ -1,16 +1,20 @@
-"""Opt-in JAX persistent compilation cache.
+"""JAX persistent compilation cache, placed from outside or at one fixed path.
 
-The fleet chunk program at M=10008 takes minutes to compile on one CPU
-core; across bench runs and test sessions the program is byte-identical,
-so the XLA compilation cache turns every run after the first into a disk
-read.  Opt in by exporting
+The fleet chunk program at M=10008 takes tens of seconds to compile; across
+bench runs, smoke runs and test sessions the program is byte-identical, so
+the XLA compilation cache turns every run after the first into a disk read.
+`enable_persistent_cache()` picks the directory by one rule:
 
-    REPRO_JAX_CACHE_DIR=/path/to/cache
+* ``JAX_COMPILATION_CACHE_DIR`` set: that directory, and no other.  JAX
+  reads the variable itself; this module only creates the directory and
+  sets no path in code.
+* unset: ``.jax_cache/`` at the root of the checkout (gitignored).  The
+  path is fixed because it is part of the cache's key — a temporary or
+  per-process directory would never hit.
 
-before running ``benchmarks/run.py`` or the test suite (tests/conftest.py
-calls `enable_persistent_cache()` at collection time).  Unset, this module
-does nothing — CI machines with ephemeral disks and single-shot runs pay
-no cache-write overhead.
+`chip_smoke.py`, ``benchmarks/run.py`` and the examples call it at start-up.
+The test suite calls it only when ``JAX_COMPILATION_CACHE_DIR`` is set
+(tests/conftest.py), so a plain test run writes nothing into the checkout.
 """
 
 from __future__ import annotations
@@ -18,29 +22,24 @@ from __future__ import annotations
 import os
 from pathlib import Path
 
-_ENV_VAR = "REPRO_JAX_CACHE_DIR"
-_enabled_dir: str | None = None
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_persistent_cache() -> str | None:
-    """Point JAX's compilation cache at ``$REPRO_JAX_CACHE_DIR``.
+def enable_persistent_cache() -> str:
+    """Turn JAX's persistent compilation cache on; returns its directory.
 
-    Returns the cache directory if enabled (creating it if needed), else
-    None.  Idempotent — safe to call from several entry points.
+    Idempotent — safe to call from several entry points.
     """
-    global _enabled_dir
-    cache_dir = os.environ.get(_ENV_VAR)
-    if not cache_dir:
-        return None
-    if _enabled_dir == cache_dir:
-        return _enabled_dir
-    Path(cache_dir).mkdir(parents=True, exist_ok=True)
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # cache everything, including sub-second compiles: the suite's many
-    # small jit programs add up on one core
+    cache_dir = os.environ.get(ENV_VAR)
+    if not cache_dir:
+        cache_dir = str(DEFAULT_DIR)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    Path(cache_dir).mkdir(parents=True, exist_ok=True)
+    # cache everything, including sub-second compiles: the many small jit
+    # programs of a study add up
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _enabled_dir = cache_dir
-    return _enabled_dir
+    return cache_dir

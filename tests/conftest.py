@@ -2,10 +2,11 @@
 
 Two jobs:
 
-1. Opt-in persistent XLA compilation cache (`REPRO_JAX_CACHE_DIR=...`):
+1. Opt-in persistent XLA compilation cache (`JAX_COMPILATION_CACHE_DIR=...`):
    the suite jit-compiles hundreds of small programs plus a handful of
    expensive fleet-scale ones; on a warm cache a full run saves minutes
-   of single-core compile time.  Unset, nothing changes.
+   of single-core compile time.  Unset, no cache is kept, so a test run
+   writes nothing into the checkout.
 
 2. When the real `hypothesis` package is unavailable (minimal containers
    where nothing can be pip-installed), install a tiny deterministic
@@ -21,14 +22,16 @@ from __future__ import annotations
 
 import functools
 import inspect
+import os
 import random
 import sys
 import types
 import zlib
 
-from repro.utils.cache import enable_persistent_cache
+from repro.utils.cache import ENV_VAR, enable_persistent_cache
 
-enable_persistent_cache()
+if os.environ.get(ENV_VAR):
+    enable_persistent_cache()
 
 try:
     import hypothesis  # noqa: F401

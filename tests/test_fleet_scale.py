@@ -26,9 +26,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import sample_path_pins
 from repro.core import balanced_pandas as bp
 from repro.core import locality as loc, simulator as sim
-from repro.core.policy import PolicyConfig, available_policies
+from repro.core.policy import available_policies
 from repro.kernels import ops as kops, ref
 from repro.sharding.sim import (
     FLEET_AUTO_THRESHOLD, FleetConfig, _build_fleet_chunk,
@@ -117,49 +118,16 @@ def test_kernel_and_segmin_paths_bitwise_in_loop(topo, rates):
 # ---------------------------------------------------------------------------
 # dense path: bitwise pins (fleet dispatch must not perturb it at all)
 
-_PIN_CFG = sim.SimConfig(topo=loc.Topology(24, 6), true_rates=loc.Rates(),
-                         p_hot=0.5, max_arrivals=24, horizon=1200,
-                         warmup=300)
-_PIN_CAP = loc.capacity_hot_rack(_PIN_CFG.topo, _PIN_CFG.true_rates, 0.5)
-
-# recorded from the dense path; exact f32 values, not approximations
-_DENSE_PINS = {
-    "balanced_pandas": {"final_n": 15.0,
-                        "mean_delay": 3.4911115169525146,
-                        "mean_n": 27.928892135620117,
-                        "throughput": 7.965555667877197},
-    "blind_pandas": {"est_alpha_mean": 0.4999604821205139, "final_n": 17.0,
-                     "mean_delay": 3.4968056678771973,
-                     "mean_n": 27.974445343017578,
-                     "throughput": 7.9633331298828125},
-    "fifo": {"drops": 0.0, "final_n": 595.0,
-             "mean_delay": 62.2972412109375, "mean_n": 498.3779296875,
-             "throughput": 7.548888683319092},
-    "jsq_maxweight": {"final_n": 18.0, "mean_delay": 3.21610951423645,
-                      "mean_n": 25.7288761138916,
-                      "throughput": 7.965555667877197},
-    "pandas_po2": {"final_n": 18.0, "mean_delay": 3.7629172801971436,
-                   "mean_n": 30.10333824157715,
-                   "throughput": 7.967777729034424},
-    "priority": {"final_n": 21.0, "mean_delay": 3.612638235092163,
-                 "mean_n": 28.901105880737305,
-                 "throughput": 7.965555667877197},
-    # signal-free slo_pandas IS balanced_pandas (bitwise, by construction)
-    "slo_pandas": {"final_n": 15.0,
-                   "mean_delay": 3.4911115169525146,
-                   "mean_n": 27.928892135620117,
-                   "throughput": 7.965555667877197},
-}
+# recorded from the dense path by tests/sample_path_pins.py; exact f32
+# values, not approximations (signal-free slo_pandas IS balanced_pandas,
+# bitwise, by construction)
+_DENSE_PINS = sample_path_pins.load()["dense"]
 
 
-@pytest.mark.parametrize("name", sorted(_DENSE_PINS))
+@pytest.mark.parametrize("name", sample_path_pins.POLICIES["dense"])
 def test_dense_path_bitwise_pinned(name):
     assert set(available_policies()) == set(_DENSE_PINS)
-    est = sim.make_estimates(_PIN_CFG, "network", 0.0, -1)
-    pol = PolicyConfig(name, {"prior": _PIN_CFG.true_rates.values}) \
-        if name == "blind_pandas" else name
-    out = sim.simulate(pol, _PIN_CFG, 0.8 * _PIN_CAP, est, seed=0)
-    assert out == _DENSE_PINS[name]
+    assert sample_path_pins.run_dense(name) == _DENSE_PINS[name]
 
 
 # ---------------------------------------------------------------------------

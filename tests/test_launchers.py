@@ -12,6 +12,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_cmd(args, timeout=900, devices=None):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    if not env.get("JAX_COMPILATION_CACHE_DIR"):
+        # the examples keep a compile cache in the checkout; a test run
+        # must not (same rule as tests/conftest.py)
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     if devices:
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     out = subprocess.run([sys.executable] + args, capture_output=True,

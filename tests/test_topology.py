@@ -1,10 +1,10 @@
 """Tier-generic topology core: K-level hierarchies, K-vector rates, the
 tier seam, the K-tier fluid capacity vs a brute-force LP, per-rack arrival
-weights, and the bitwise pre-refactor pins.
+weights, and the K=3 sample-path pins.
 
-The pinned values were recorded from the 3-tier code before the
-tier-generic refactor (same container, jax 0.4.37); the K=3 flat-rack
-default must keep reproducing those sample paths exactly.
+The K=3 flat-rack default must keep reproducing the sample paths pinned in
+tests/sample_path_pins.json (first recorded from the 3-tier code before
+the tier-generic refactor, re-recorded per JAX build).
 """
 
 import jax
@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import sample_path_pins
 from repro import workloads as wl
 from repro.core import locality as loc, simulator as sim
 from repro.core.cluster import pair_worker_tiers, tier_of, worker_tiers
@@ -204,63 +205,23 @@ def test_capacity_k3_matches_seed_closed_form():
 
 # ------------------------------------------------------- bitwise K=3 pins --
 
-# Recorded from the pre-refactor 3-tier implementation: Topology(12, 4),
-# Rates(0.5, 0.45, 0.25), p_hot=0.5, max_arrivals=16, horizon=2000,
-# warmup=500, lam = 0.8 * capacity, seed 3.
-PINNED_12x4 = {
-    "balanced_pandas": {"final_n": 27.0, "mean_delay": 4.029056549072266,
-                        "mean_n": 17.190641403198242,
-                        "throughput": 4.24066686630249},
-    "jsq_maxweight": {"final_n": 23.0, "mean_delay": 3.957812547683716,
-                      "mean_n": 16.886667251586914,
-                      "throughput": 4.241333484649658},
-    "priority": {"final_n": 15.0, "mean_delay": 3.951564311981201,
-                 "mean_n": 16.860008239746094,
-                 "throughput": 4.247333526611328},
-    "fifo": {"drops": 0.0, "final_n": 292.0,
-             "mean_delay": 54.13591766357422, "mean_n": 230.9799346923828,
-             "throughput": 4.11133337020874},
-    "pandas_po2": {"final_n": 26.0, "mean_delay": 4.019688606262207,
-                   "mean_n": 17.150672912597656,
-                   "throughput": 4.243333339691162},
-    "blind_pandas": {"est_alpha_mean": 0.47840529680252075, "final_n": 27.0,
-                     "mean_delay": 4.039682388305664,
-                     "mean_n": 17.235979080200195,
-                     "throughput": 4.239999771118164},
-}
-
-# Paper-scale second pin: Topology(24, 6), max_arrivals=24, horizon=1500,
-# warmup=300, lam = 0.9 * capacity (= 9.0), seed 7.
-PINNED_24x6 = {
-    "balanced_pandas": {"final_n": 35.0, "mean_delay": 4.965092182159424,
-                        "mean_n": 44.685829162597656,
-                        "throughput": 9.112500190734863},
-    "jsq_maxweight": {"final_n": 21.0, "mean_delay": 5.3194451332092285,
-                      "mean_n": 47.87500762939453,
-                      "throughput": 9.129166603088379},
-}
+# Sample paths of the K=3 flat-rack default, recorded by
+# tests/sample_path_pins.py (the recipes, and how to re-record them after
+# a JAX upgrade, are there).
+PINS = sample_path_pins.load()
 
 
-@pytest.mark.parametrize("algo", sorted(PINNED_12x4))
+@pytest.mark.parametrize("algo", sample_path_pins.POLICIES["k3_12x4"])
 def test_k3_default_reproduces_prerefactor_sample_paths(algo):
-    cfg = sim.SimConfig(topo=loc.Topology(12, 4), true_rates=loc.Rates(),
-                        p_hot=0.5, max_arrivals=16, horizon=2000, warmup=500)
-    cap = loc.capacity_hot_rack(cfg.topo, cfg.true_rates, cfg.p_hot)
-    est = sim.make_estimates(cfg, "network", 0.0, -1)
-    out = sim.simulate(algo, cfg, 0.8 * cap, est, seed=3)
-    for k, v in PINNED_12x4[algo].items():
+    out = sample_path_pins.run_12x4(algo)
+    for k, v in PINS["k3_12x4"][algo].items():
         assert out[k] == pytest.approx(v, rel=1e-6, abs=1e-9), (algo, k)
 
 
-@pytest.mark.parametrize("algo", sorted(PINNED_24x6))
+@pytest.mark.parametrize("algo", sample_path_pins.POLICIES["k3_24x6"])
 def test_k3_paper_scale_pin(algo):
-    cfg = sim.SimConfig(topo=loc.Topology(24, 6), true_rates=loc.Rates(),
-                        p_hot=0.5, max_arrivals=24, horizon=1500, warmup=300)
-    cap = loc.capacity_hot_rack(cfg.topo, cfg.true_rates, cfg.p_hot)
-    assert cap == pytest.approx(10.0)
-    est = sim.make_estimates(cfg, "network", 0.0, -1)
-    out = sim.simulate(algo, cfg, 0.9 * cap, est, seed=7)
-    for k, v in PINNED_24x6[algo].items():
+    out = sample_path_pins.run_24x6(algo)
+    for k, v in PINS["k3_24x6"][algo].items():
         assert out[k] == pytest.approx(v, rel=1e-6, abs=1e-9), (algo, k)
 
 
