@@ -14,10 +14,7 @@ Every arm reports BOTH a steady-state rate row (``sim_slots_per_sec_*``,
 min-of-3 on the already-compiled executable) and a compile-time row
 (``sim_compile_sec_*``, the XLA lowering+compile step timed separately
 via AOT compilation) — so a compile-time regression can't hide inside a
-throughput number or vice versa.  Pass an
-`repro.telemetry.EventRecorder` as ``tracer`` to additionally wrap the
-compile and dispatch phases in Chrome-trace spans
-(``benchmarks/run.py --trace``).
+throughput number or vice versa.
 """
 
 from __future__ import annotations
@@ -34,7 +31,7 @@ def _timed(run, args) -> float:
     return time.perf_counter() - t0
 
 
-def _compile_split(run, args, tracer=None, label=""):
+def _compile_split(run, args):
     """(compile_sec, steady_sec): AOT-split timings of a jitted callable.
 
     Compile time is the real XLA compile (``.lower().compile()``), not a
@@ -44,20 +41,17 @@ def _compile_split(run, args, tracer=None, label=""):
     trajectory).
     """
     import jax
-    from repro.telemetry import maybe_span
 
-    with maybe_span(tracer, f"compile:{label}", cat="compile"):
-        lowered = run.lower(*args)
-        t0 = time.perf_counter()
-        compiled = lowered.compile()
-        t_compile = time.perf_counter() - t0
+    lowered = run.lower(*args)
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    t_compile = time.perf_counter() - t0
     jax.block_until_ready(compiled(*args))  # warm: allocs, autotuning
-    with maybe_span(tracer, f"steady:{label}", cat="kernel"):
-        dt = min(_timed(compiled, args) for _ in range(3))
+    dt = min(_timed(compiled, args) for _ in range(3))
     return t_compile, dt
 
 
-def bench(fast: bool = True, tracer=None):
+def bench(fast: bool = True):
     import jax
     from repro.core import locality as loc, simulator as sim
     from repro.core.policy import PolicyConfig, available_policies
@@ -81,8 +75,7 @@ def bench(fast: bool = True, tracer=None):
             run = jax.jit(sim._build_run(policy, cfg))
             args = (np.float32(0.8 * cap), est.astype(np.float32),
                     np.uint32(0))
-            t_compile, dt = _compile_split(run, args, tracer,
-                                           f"{name}_{label}")
+            t_compile, dt = _compile_split(run, args)
             derived = (f"policy={name},topology={label},K={topo.num_tiers},"
                        f"M={topo.num_servers},horizon={horizon}")
             rows.append((f"sim_slots_per_sec_{name}_{label}",
@@ -92,7 +85,7 @@ def bench(fast: bool = True, tracer=None):
     return rows
 
 
-def bench_scaling(fast: bool = True, tracer=None):
+def bench_scaling(fast: bool = True):
     """Fleet-scale throughput: simulated slots/sec of the fleet fast path
     (sharding.sim) at M=2400 and M=10008 servers, plus the dense
     reference arm at M=2400.
@@ -127,8 +120,7 @@ def bench_scaling(fast: bool = True, tracer=None):
         init, chunk = _build_fleet_chunk("balanced_pandas", cfg, fc)
         run = jax.jit(chunk)  # no donation: _compile_split reuses args
         args = (init(), np.int32(0), np.float32(lam), est, np.uint32(0))
-        t_compile, dt = _compile_split(run, args, tracer,
-                                       f"scaling_kernel_M{m}")
+        t_compile, dt = _compile_split(run, args)
         derived = (f"path=fleet,policy=balanced_pandas,M={m},"
                    f"chunk={fc.chunk},rounds={fc.rounds},"
                    f"batch={batch},horizon={horizon}")
@@ -148,8 +140,7 @@ def bench_scaling(fast: bool = True, tracer=None):
         est = loc.per_server_rates(rates.as_array(), m).astype(np.float32)
         run = jax.jit(sim._build_run("balanced_pandas", cfg))
         args = (np.float32(lam), est, np.uint32(0))
-        t_compile, dt = _compile_split(run, args, tracer,
-                                       f"scaling_dense_M{m}")
+        t_compile, dt = _compile_split(run, args)
         derived = (f"path=dense,policy=balanced_pandas,M={m},"
                    f"horizon={dense_horizon}")
         rows.append((f"sim_slots_per_sec_scaling_dense_M{m}",
@@ -163,7 +154,7 @@ def bench_scaling(fast: bool = True, tracer=None):
     return rows
 
 
-def bench_placement(fast: bool = True, tracer=None):
+def bench_placement(fast: bool = True):
     """Placement-sampler throughput: simulator slots/sec of the default
     policy under every registered replica placement, 3-tier and 4-tier.
 
@@ -193,8 +184,7 @@ def bench_placement(fast: bool = True, tracer=None):
         for plc in available_placements():
             run = jax.jit(sim._build_run("balanced_pandas", cfg,
                                          placement=plc))
-            t_compile, dt = _compile_split(run, args, tracer,
-                                           f"placement_{plc}_{label}")
+            t_compile, dt = _compile_split(run, args)
             derived = (f"placement={plc},policy=balanced_pandas,"
                        f"topology={label},K={topo.num_tiers},"
                        f"M={topo.num_servers},horizon={horizon}")
@@ -205,7 +195,7 @@ def bench_placement(fast: bool = True, tracer=None):
     return rows
 
 
-def bench_control(fast: bool = True, tracer=None):
+def bench_control(fast: bool = True):
     """Control-plane throughput: simulator slots/sec of the default policy
     with each control arm compiled into the scan — no control (the
     bitwise-pinned reference), token-bucket admission, closed-loop load
@@ -239,8 +229,7 @@ def bench_control(fast: bool = True, tracer=None):
     for label, pol, control, telemetry in arms:
         run = jax.jit(sim._build_run(pol, cfg, control=control,
                                      telemetry=telemetry))
-        t_compile, dt = _compile_split(run, args, tracer,
-                                       f"control_{label}")
+        t_compile, dt = _compile_split(run, args)
         derived = (f"control={label},policy={pol},K={topo.num_tiers},"
                    f"M={topo.num_servers},horizon={horizon},"
                    f"telemetry={bool(telemetry)}")
@@ -251,7 +240,7 @@ def bench_control(fast: bool = True, tracer=None):
     return rows
 
 
-def bench_replication(fast: bool = True, tracer=None):
+def bench_replication(fast: bool = True):
     """Replication-lifecycle throughput: simulator slots/sec of the default
     policy under every registered replication controller, with the
     server_loss scenario engaged so the lifecycle machinery (chunk
@@ -278,8 +267,7 @@ def bench_replication(fast: bool = True, tracer=None):
     for ctrl, scen in arms:
         run = jax.jit(sim._build_run("balanced_pandas", cfg, scenario=scen,
                                      replication=ctrl))
-        t_compile, dt = _compile_split(run, args, tracer,
-                                       f"replication_{ctrl}_{scen}")
+        t_compile, dt = _compile_split(run, args)
         derived = (f"replication={ctrl},scenario={scen},"
                    f"policy=balanced_pandas,K={topo.num_tiers},"
                    f"M={topo.num_servers},horizon={horizon}")

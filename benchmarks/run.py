@@ -65,9 +65,8 @@ def main() -> None:
                          "records + per-section wall times)")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a Chrome trace-event JSON of the run "
-                         "(section spans, per-arm compile/steady spans, "
-                         "engine route/admit/decode events) — load it at "
-                         "https://ui.perfetto.dev")
+                         "(section spans, engine route/admit/decode "
+                         "events) — load it at https://ui.perfetto.dev")
     args = ap.parse_args()
     fast = not args.full
     only = set(args.only.split(",")) if args.only else None
@@ -124,13 +123,11 @@ def main() -> None:
     section("fig56", lambda: figures.fig56_over(fast))
     section("drift", lambda: figures.fig_drift(fast))
     section("kernels", lambda: bench_kernels.bench(fast))
-    section("sim_throughput", lambda: bench_sim.bench(fast, tracer=tracer))
-    section("scaling", lambda: bench_sim.bench_scaling(fast, tracer=tracer))
-    section("placement",
-            lambda: bench_sim.bench_placement(fast, tracer=tracer))
-    section("replication",
-            lambda: bench_sim.bench_replication(fast, tracer=tracer))
-    section("control", lambda: bench_sim.bench_control(fast, tracer=tracer))
+    section("sim_throughput", lambda: bench_sim.bench(fast))
+    section("scaling", lambda: bench_sim.bench_scaling(fast))
+    section("placement", lambda: bench_sim.bench_placement(fast))
+    section("replication", lambda: bench_sim.bench_replication(fast))
+    section("control", lambda: bench_sim.bench_control(fast))
     section("serving", lambda: bench_serving.bench(fast, tracer=tracer))
     section("serving_scenarios", lambda: bench_serving.bench_scenarios(fast))
     section("serving_control",

@@ -181,9 +181,11 @@ def slot_step(s: PandasState, key: jax.Array, types: jnp.ndarray,
     def body(i, st):
         return route_one(st, jax.random.fold_in(k_route, i), types[i],
                          active[i], est, anc, server_mask=server_mask)
-    s = jax.lax.fori_loop(0, n_arr, body, s)
+    with jax.named_scope("sim.route"):
+        s = jax.lax.fori_loop(0, n_arr, body, s)
 
-    return serve_and_schedule(s, k_serve, true_rates)
+    with jax.named_scope("sim.serve"):
+        return serve_and_schedule(s, k_serve, true_rates)
 
 
 @register_policy

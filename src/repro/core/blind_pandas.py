@@ -92,22 +92,27 @@ class BlindPandasPolicy(SlotPolicy):
         def body(i, st):
             return bp.route_one(st, jax.random.fold_in(k_route, i), types[i],
                                 active[i], my_est, anc)
-        core = jax.lax.fori_loop(0, n_arr, body, core)
+        with jax.named_scope("sim.route"):
+            core = jax.lax.fori_loop(0, n_arr, body, core)
 
-        # Exactly balanced_pandas's service/scheduling dynamics, via the
-        # shared helpers — only the estimator bookkeeping is new.
-        done, completions = bp.service_completions(core, k_serve, true_rates)
+        with jax.named_scope("sim.serve"):
+            # Exactly balanced_pandas's service/scheduling dynamics, via
+            # the shared helpers — only the estimator bookkeeping is new.
+            done, completions = bp.service_completions(core, k_serve,
+                                                       true_rates)
 
-        # Observe: a task completing this slot took age+1 slots of service.
-        k = s.tbar.shape[1]
-        tier = jnp.clip(core.serving - 1, 0, k - 1)
-        tbar = ewma_time_update(s.tbar, done, tier,
-                                (s.age + 1).astype(jnp.float32), self.decay)
+            # Observe: a task completing this slot took age+1 slots of
+            # service.
+            k = s.tbar.shape[1]
+            tier = jnp.clip(core.serving - 1, 0, k - 1)
+            tbar = ewma_time_update(s.tbar, done, tier,
+                                    (s.age + 1).astype(jnp.float32),
+                                    self.decay)
 
-        new_core = bp.schedule_idle(core, done)
-        # Tasks that survived the slot age one slot; completed / fresh /
-        # idle servers reset to zero.
-        age = jnp.where((core.serving > 0) & ~done, s.age + 1, 0)
+            new_core = bp.schedule_idle(core, done)
+            # Tasks that survived the slot age one slot; completed / fresh
+            # / idle servers reset to zero.
+            age = jnp.where((core.serving > 0) & ~done, s.age + 1, 0)
         return BlindPandasState(new_core, age, tbar), completions
 
     def num_in_system(self, s: BlindPandasState) -> jnp.ndarray:

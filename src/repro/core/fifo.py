@@ -65,17 +65,20 @@ def slot_step(s: FifoState, key: jax.Array, types: jnp.ndarray,
         drops = drops + (active[i] & ~fits).astype(jnp.int32)
         return buf, head, count, drops
 
-    buf, head, count, drops = jax.lax.fori_loop(
-        0, n_arr, push, (s.buf, s.head, s.count, s.drops))
+    with jax.named_scope("sim.route"):
+        buf, head, count, drops = jax.lax.fori_loop(
+            0, n_arr, push, (s.buf, s.head, s.count, s.drops))
 
-    # 2. Service completions at the CURRENT true rates (class stored, rate
-    #    re-derived each slot -> scenario drift reaches in-flight tasks).
-    done = jax.random.bernoulli(k_serve, tier_rates(s.serving_tier, tmk))
-    completions = jnp.sum(done).astype(jnp.int32)
-    serving_tier = jnp.where(done, 0, s.serving_tier)
+    with jax.named_scope("sim.serve"):
+        # 2. Service completions at the CURRENT true rates (class stored,
+        #    rate re-derived each slot -> scenario drift reaches in-flight
+        #    tasks).
+        done = jax.random.bernoulli(k_serve, tier_rates(s.serving_tier, tmk))
+        completions = jnp.sum(done).astype(jnp.int32)
+        serving_tier = jnp.where(done, 0, s.serving_tier)
 
-    # 3. Idle servers pop heads in random server order.
-    order = jax.random.permutation(k_perm, serving_tier.shape[0])
+        # 3. Idle servers pop heads in random server order.
+        order = jax.random.permutation(k_perm, serving_tier.shape[0])
 
     def pop(i, st):
         head, count, serving_tier = st
@@ -89,8 +92,9 @@ def slot_step(s: FifoState, key: jax.Array, types: jnp.ndarray,
         count = count - take.astype(jnp.int32)
         return head, count, serving_tier
 
-    head, count, serving_tier = jax.lax.fori_loop(
-        0, serving_tier.shape[0], pop, (head, count, serving_tier))
+    with jax.named_scope("sim.serve"):
+        head, count, serving_tier = jax.lax.fori_loop(
+            0, serving_tier.shape[0], pop, (head, count, serving_tier))
 
     return FifoState(buf, head, count, serving_tier, drops), completions
 

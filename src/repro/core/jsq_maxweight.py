@@ -52,14 +52,17 @@ def slot_step(s: JsqMwState, key: jax.Array, types: jnp.ndarray,
     def body(i, q):
         return claiming.jsq_route_one(q, jax.random.fold_in(k_route, i),
                                       types[i], active[i])
-    q = jax.lax.fori_loop(0, n_arr, body, s.q)
+    with jax.named_scope("sim.route"):
+        q = jax.lax.fori_loop(0, n_arr, body, s.q)
 
-    # 2. Service completions at the CURRENT true rates (re-derived from the
-    #    stored class each slot, so scenario drift reaches in-flight tasks).
-    done = jax.random.bernoulli(
-        k_serve, claiming.tier_rates(s.serving_tier, tmk))
-    completions = jnp.sum(done).astype(jnp.int32)
-    serving_tier = jnp.where(done, 0, s.serving_tier)
+    with jax.named_scope("sim.serve"):
+        # 2. Service completions at the CURRENT true rates (re-derived from
+        #    the stored class each slot, so scenario drift reaches
+        #    in-flight tasks).
+        done = jax.random.bernoulli(
+            k_serve, claiming.tier_rates(s.serving_tier, tmk))
+        completions = jnp.sum(done).astype(jnp.int32)
+        serving_tier = jnp.where(done, 0, s.serving_tier)
 
     # 3. MaxWeight claims: weighted queue lengths with *estimated* rates.
     sid = jnp.arange(q.shape[0])
@@ -71,8 +74,9 @@ def slot_step(s: JsqMwState, key: jax.Array, types: jnp.ndarray,
     def tier_fn(m, n):
         return claiming.pair_tier(m, n, anc)
 
-    q, serving_tier = claiming.claim_loop(q, serving_tier, k_claim,
-                                          score_fn, tier_fn)
+    with jax.named_scope("sim.serve"):
+        q, serving_tier = claiming.claim_loop(q, serving_tier, k_claim,
+                                              score_fn, tier_fn)
     return JsqMwState(q, serving_tier), completions
 
 

@@ -70,9 +70,11 @@ def slot_step(s: bp.PandasState, key: jax.Array, types: jnp.ndarray,
     def body(i, st):
         return route_one_po_d(st, jax.random.fold_in(k_route, i), types[i],
                               active[i], est, anc, d)
-    s = jax.lax.fori_loop(0, n_arr, body, s)
+    with jax.named_scope("sim.route"):
+        s = jax.lax.fori_loop(0, n_arr, body, s)
 
-    return bp.serve_and_schedule(s, k_serve, true_rates)
+    with jax.named_scope("sim.serve"):
+        return bp.serve_and_schedule(s, k_serve, true_rates)
 
 
 @register_policy

@@ -54,7 +54,7 @@ from repro import workloads as wl
 from repro.placement import PlacementLike, make_placement
 from repro.replication import ReplicationLike, make_replication
 from repro.telemetry import (SimTelemetry, TelemetryLike,
-                             as_telemetry_config)
+                             as_telemetry_config, maybe_span)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -242,7 +242,7 @@ def _build_run(policy_like: PolicyLike, cfg: SimConfig,
     lam_scale = wl.mean_lam_mult_over(sched, cfg.warmup, cfg.horizon)
     init = functools.partial(policy.init_state, topo)
 
-    def run(lam_total, est, seed):
+    def run_body(lam_total, est, seed):
         base = jax.random.PRNGKey(seed)
 
         def step(carry, t):
@@ -263,10 +263,11 @@ def _build_run(policy_like: PolicyLike, cfg: SimConfig,
             # identical across policies -> paired comparisons (common
             # random numbers).  The control plane consumes no random bits,
             # so CRN coupling survives engagement too.
-            types, active = loc.sample_arrivals_at(
-                k_arr, rack_of, lam_t, knobs.p_hot,
-                knobs.hot_rack, cfg.max_arrivals, knobs.rack_weights,
-                type_sampler=sample_types)
+            with jax.named_scope("sim.arrivals"):
+                types, active = loc.sample_arrivals_at(
+                    k_arr, rack_of, lam_t, knobs.p_hot,
+                    knobs.hot_rack, cfg.max_arrivals, knobs.rack_weights,
+                    type_sampler=sample_types)
             server_mask = None
             if ctl is not None:
                 # admission trims the lane mask BEFORE routing; autoscale
@@ -352,6 +353,12 @@ def _build_run(policy_like: PolicyLike, cfg: SimConfig,
         if tel is not None:
             _merge_metrics(out, tel.metrics(carry[i_tel]), "telemetry")
         return out
+
+    def run(lam_total, est, seed):
+        # runs only while JAX traces the program: the trace's count of
+        # `sim.trace` spans is the count of program traces
+        with maybe_span(None, "sim.trace"):
+            return run_body(lam_total, est, seed)
 
     return run
 
@@ -450,12 +457,16 @@ def sweep(policy: PolicyLike, cfg: SimConfig, lam_grid: np.ndarray,
         from repro.sharding import sim as fleet_sim
         return fleet_sim.fleet_sweep(policy, cfg, lam_grid, est_stack,
                                      seeds, fleet)
-    run = _build_run(policy, cfg, scenario, placement, replication,
-                     telemetry, control)
-    f = jax.vmap(jax.vmap(jax.vmap(run, (None, None, 0)), (None, 0, None)),
-                 (0, None, None))
-    f = jax.jit(f)
-    out = f(jnp.asarray(lam_grid, jnp.float32),
-            jnp.asarray(est_stack, jnp.float32),
-            jnp.asarray(seeds, jnp.uint32))
-    return {k: np.asarray(v) for k, v in out.items()}
+    # sim.prepare: build, trace, lower, compile or read the cache, enqueue;
+    # sim.fetch: wait for the device and copy the metrics to the host
+    with maybe_span(None, "sim.prepare"):
+        run = _build_run(policy, cfg, scenario, placement, replication,
+                         telemetry, control)
+        f = jax.vmap(jax.vmap(jax.vmap(run, (None, None, 0)),
+                              (None, 0, None)), (0, None, None))
+        f = jax.jit(f)
+        out = f(jnp.asarray(lam_grid, jnp.float32),
+                jnp.asarray(est_stack, jnp.float32),
+                jnp.asarray(seeds, jnp.uint32))
+    with maybe_span(None, "sim.fetch"):
+        return {k: np.asarray(v) for k, v in out.items()}

@@ -115,9 +115,12 @@ class SloPandasPolicy(SlotPolicy):
             return _route_one_slo(st, jax.random.fold_in(k_route, i),
                                   types[i], active[i], est, anc, server_mask,
                                   breach, self.drain_bias)
-        s = jax.lax.fori_loop(0, types.shape[0], body, s)
-        done, completions = bp.service_completions(s, k_serve, true_rates)
-        return _schedule_idle_slo(s, done, breach), completions
+        with jax.named_scope("sim.route"):
+            s = jax.lax.fori_loop(0, types.shape[0], body, s)
+        with jax.named_scope("sim.serve"):
+            done, completions = bp.service_completions(s, k_serve,
+                                                       true_rates)
+            return _schedule_idle_slo(s, done, breach), completions
 
     def num_in_system(self, s: bp.PandasState) -> jnp.ndarray:
         return bp.num_in_system(s)

@@ -162,6 +162,9 @@ def fleet_route_pallas(q: jnp.ndarray, serving: jnp.ndarray,
                                          lambda i, j: (i, 0))
     score, server, tier = pl.pallas_call(
         kernel,
+        # the kernel's name in compiled HLO and in profiler traces, kept
+        # whatever the wrapper is called
+        name="fleet_route_kernel",
         grid=grid,
         in_specs=[per_server(k), per_server(k), per_server(1),
                   per_server(depth), per_task(tasks.shape[1])],

@@ -74,6 +74,7 @@ from repro.core import balanced_pandas as bp
 from repro.core import locality as loc
 from repro.core.policy import PolicyLike, make_policy
 from repro.kernels import ops as kops
+from repro.telemetry import maybe_span
 
 # Auto-engagement floor for core.simulator's ``fleet=None``: every
 # paper-scale configuration (M <= a few hundred) stays on the faithful
@@ -304,23 +305,27 @@ def _route_batch_pandas(s: bp.PandasState, est, ctx: FleetCtx, locs, active,
     pending = active
     for r in range(fc.rounds):
         w = bp.workload(s, est)
-        if use_pallas:
-            best_i, best_t, best_v = kops.fleet_route(s.q, s.serving, est,
-                                                      ctx.anc, locs)
-        else:
-            best_i, best_t, best_v = _private_route_segmin(w, est, ctx, locs)
+        with jax.named_scope("sim.private"):
+            if use_pallas:
+                best_i, best_t, best_v = kops.fleet_route(
+                    s.q, s.serving, est, ctx.anc, locs)
+            else:
+                best_i, best_t, best_v = _private_route_segmin(w, est, ctx,
+                                                               locs)
 
-        # pool (remote tier) water-fill parameters from the same snapshot
-        pr = est[:, k - 1]
-        p = w / pr - pr * 1e-6
-        d = 1.0 / (pr * pr)
-        s_priv = jnp.where(pending, best_v, jnp.float32(-3e38))
+        with jax.named_scope("sim.fill"):
+            # pool (remote tier) water-fill parameters from the same
+            # snapshot
+            pr = est[:, k - 1]
+            p = w / pr - pr * 1e-6
+            d = 1.0 / (pr * pr)
+            s_priv = jnp.where(pending, best_v, jnp.float32(-3e38))
 
-        def demand(y):
-            return jnp.sum((pending & (best_v > y)).astype(jnp.float32))
+            def demand(y):
+                return jnp.sum((pending & (best_v > y)).astype(jnp.float32))
 
-        y1 = _water_level(p, d, demand, jnp.max(s_priv), batch,
-                          fc.fill_iters)
+            y1 = _water_level(p, d, demand, jnp.max(s_priv), batch,
+                              fc.fill_iters)
 
         # private rank clamp: the r-th claimant of a server stays private
         # only while its filled score is still under the water level
@@ -343,15 +348,16 @@ def _route_batch_pandas(s: bp.PandasState, est, ctx: FleetCtx, locs, active,
 
     # final pass: pool assignment at the re-raised level
     pool = pending & ~stay
-    n_pool = jnp.sum(pool.astype(jnp.float32))
-    y2 = _water_level(p, d, lambda y: n_pool, jnp.max(s_priv), batch,
-                      fc.fill_iters)
-    caps = jnp.clip(jnp.ceil((y2 - p) / d), 0.0, float(batch)
-                    ).astype(jnp.int32)
-    cum = jnp.cumsum(caps)
-    pool_rank = jnp.cumsum(pool.astype(jnp.int32)) - 1
-    pool_srv = jnp.clip(jnp.searchsorted(cum, pool_rank, side="right"),
-                        0, m - 1).astype(jnp.int32)
+    with jax.named_scope("sim.fill"):
+        n_pool = jnp.sum(pool.astype(jnp.float32))
+        y2 = _water_level(p, d, lambda y: n_pool, jnp.max(s_priv), batch,
+                          fc.fill_iters)
+        caps = jnp.clip(jnp.ceil((y2 - p) / d), 0.0, float(batch)
+                        ).astype(jnp.int32)
+        cum = jnp.cumsum(caps)
+        pool_rank = jnp.cumsum(pool.astype(jnp.int32)) - 1
+        pool_srv = jnp.clip(jnp.searchsorted(cum, pool_rank, side="right"),
+                            0, m - 1).astype(jnp.int32)
 
     srv = jnp.where(stay, best_i, pool_srv)
     tier = jnp.where(stay, best_t, k - 1)
@@ -422,6 +428,11 @@ def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
                 jnp.float32(0.0), jnp.float32(0.0), jnp.int32(0))
 
     def chunk(carry, t0, lam, est, seed):
+        # runs only while JAX traces the program (see core/simulator)
+        with maybe_span(None, "sim.trace"):
+            return chunk_body(carry, t0, lam, est, seed)
+
+    def chunk_body(carry, t0, lam, est, seed):
         base_key = jax.random.PRNGKey(seed)
 
         def step(c, t):
@@ -429,15 +440,19 @@ def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
             s = bp.PandasState(q, serving)
             key_t = jax.random.fold_in(base_key, t)
             k_arr, k_algo = jax.random.split(key_t)
-            types, active = _sample_arrivals(k_arr, ctx, lam, p_hot, batch)
+            with jax.named_scope("sim.arrivals"):
+                types, active = _sample_arrivals(k_arr, ctx, lam, p_hot,
+                                                 batch)
             k_route, k_serve = jax.random.split(k_algo)
-            if policy.name == "pandas_po2":
-                s = _route_batch_po2(s, est, ctx, types, active, k_route,
-                                     d_choices)
-            else:
-                s = _route_batch_pandas(s, est, ctx, types, active, fc,
-                                        use_pallas)
-            s, compl_t = bp.serve_and_schedule(s, k_serve, true_k)
+            with jax.named_scope("sim.route"):
+                if policy.name == "pandas_po2":
+                    s = _route_batch_po2(s, est, ctx, types, active, k_route,
+                                         d_choices)
+                else:
+                    s = _route_batch_pandas(s, est, ctx, types, active, fc,
+                                            use_pallas)
+            with jax.named_scope("sim.serve"):
+                s, compl_t = bp.serve_and_schedule(s, k_serve, true_k)
             n = (jnp.sum(s.q) + jnp.sum(s.serving > 0)).astype(jnp.float32)
             in_w = (t >= warmup).astype(jnp.float32)
             n_meas2 = n_meas + in_w

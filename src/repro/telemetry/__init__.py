@@ -13,8 +13,10 @@
 2. **Host-side event tracing** (`events.py`) — a ring-buffered
    `EventRecorder` the serving engine, the data pipeline, the host
    replication lifecycle and the benches emit typed events into, with a
-   Chrome trace-event JSON exporter viewable in Perfetto and a
-   span/timer hook for kernel-vs-host time attribution.
+   Chrome trace-event JSON exporter viewable in Perfetto.  Its span
+   helper (`maybe_span`) also annotates the `jax.profiler` trace, where
+   the simulator's host spans (`sim.prepare`, `sim.fetch`, `sim.trace`)
+   sit beside its device scopes (`sim.arrivals`, `sim.route`, ...).
 
 See docs/observability.md for recorder configuration, the histogram
 error bound, and the trace-event schema.

@@ -3,7 +3,7 @@
 One `EventRecorder` per run: consumers (`serve/engine.py`,
 `data/pipeline.py`, `replication/host.py`, the benches) emit typed
 events — route decisions, admissions, replica reads, failovers,
-migration starts/commits, failure windows, kernel-dispatch spans — into
+migration starts/commits, failure windows, section spans — into
 a bounded ring buffer (a deque: the newest `capacity` events win, and
 the eviction count is reported, never hidden).  `to_chrome()` serializes
 the buffer as Chrome trace-event JSON, the format Perfetto
@@ -13,9 +13,15 @@ Timestamps are microseconds (`ts`/`dur`), per the trace-event spec.
 Emitters on a virtual clock (engine steps, the pipeline's virtual time)
 pass explicit ``ts_us`` values — the convention throughout this repo is
 ONE CLOCK UNIT = 1 ms, i.e. ``ts_us = clock * 1000`` — while wall-clock
-spans (`span`, the kernel-dispatch timer in the benches) use a
-`perf_counter` anchored at recorder construction.  Phase codes used:
-``X`` complete (ts + dur), ``i`` instant, ``C`` counter, ``M`` metadata.
+spans (`span`) use a `perf_counter` anchored at recorder construction.
+Phase codes used: ``X`` complete (ts + dur), ``i`` instant, ``C``
+counter, ``M`` metadata.
+
+`span` and `maybe_span` are the program's one span helper: each also
+enters a `jax.profiler.TraceAnnotation` of the same name, so the span
+lands in a `jax.profiler` trace on the clock of the device's events —
+with no recorder too (``maybe_span(None, ...)``).  When no profiler
+trace is being taken the annotation costs a flag check.
 """
 
 from __future__ import annotations
@@ -23,9 +29,11 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Union
+
+import jax
 
 #: trace-event phases this recorder emits / the validator accepts
 PHASES = ("X", "B", "E", "i", "I", "C", "M")
@@ -85,11 +93,12 @@ class EventRecorder:
 
     @contextmanager
     def span(self, name: str, cat: str = "host", tid: int = 0, **args: Any):
-        """Wall-clock span: wraps a host-side region (e.g. the Pallas
-        kernel dispatch path in the benches) as one complete event."""
+        """Wall-clock span: wraps a host-side region as one complete event
+        in the ring, and as a profiler annotation of the same name."""
         t0 = self.now_us()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             self.complete(name, t0, self.now_us() - t0, cat=cat, tid=tid,
                           **args)
@@ -123,10 +132,10 @@ class EventRecorder:
 
 def maybe_span(tracer: Optional[EventRecorder], name: str,
                cat: str = "host", tid: int = 0, **args: Any):
-    """`tracer.span(...)` or a no-op context when tracing is off — the
-    zero-overhead guard every instrumented call site uses."""
+    """`tracer.span(...)`, or with no recorder the profiler annotation
+    alone: the span every instrumented call site opens."""
     if tracer is None:
-        return nullcontext()
+        return jax.profiler.TraceAnnotation(name)
     return tracer.span(name, cat=cat, tid=tid, **args)
 
 
