@@ -39,8 +39,10 @@ the scale-invariance finding that motivates them):
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import threading
 from typing import Any, Dict
 
 import jax
@@ -363,6 +365,80 @@ def _build_run(policy_like: PolicyLike, cfg: SimConfig,
     return run
 
 
+# Jitted dense study programs, kept per configuration and dropped least
+# recently used first.  A repeated `sweep` or `simulate` of one
+# configuration at the same input shapes (a study cut into calls, as the
+# benchmark window does; fig5/6 after fig3/4 in `benchmarks/figures.py`)
+# reuses its `jax.jit` object, so JAX neither traces, lowers nor loads the
+# program again; only the traced arguments (loads, estimates, seeds)
+# change.  The studies in `core/robustness.py` call each configuration
+# once, so within one of them every call builds its program.
+# Programs, not compiled executables: `jax.clear_caches()` still sends the
+# next call through a fresh trace.
+_PROGRAMS_MAX = 32
+_PROGRAMS: "collections.OrderedDict[tuple, Any]" = collections.OrderedDict()
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def _value_key(x):
+    """`x` as a hashable key of its value, with the type of every part, or
+    None when it has no such form: a dict, an array, a mutable object or
+    an instance that hashes by identity.  The types keep ``6000`` and
+    ``6000.0`` (or ``True`` and ``1``) apart, which would build different
+    programs though they compare equal."""
+    if x is None or isinstance(x, (str, bool, int, float)):
+        return (type(x), x)
+    if isinstance(x, tuple):
+        parts = tuple(_value_key(v) for v in x)
+    elif isinstance(x, loc.Rates):
+        parts = x.values
+    elif (dataclasses.is_dataclass(x) and not isinstance(x, type)
+          and x.__dataclass_params__.frozen and x.__dataclass_params__.eq):
+        parts = tuple(_value_key(getattr(x, f.name))
+                      for f in dataclasses.fields(x))
+    else:
+        return None
+    return None if None in parts else (type(x), parts)
+
+
+def _program(kind: str, policy_like: PolicyLike, cfg: SimConfig,
+             scenario: wl.ScenarioLike, placement: PlacementLike,
+             replication: ReplicationLike, telemetry: TelemetryLike,
+             control: ControlLike):
+    """The jitted program of a dense study: `run` for ``"simulate"``,
+    `run` vmapped over (loads, estimates, seeds) for ``"sweep"``.
+
+    Kept in `_PROGRAMS` under the value of every argument `_build_run`
+    reads; when one of them has no value key (`_value_key`), the program
+    is built and jitted fresh and not kept.  Input shapes are not part of
+    the key: the `jax.jit` object keys its own compilations on them."""
+    args = (policy_like, cfg, scenario, placement, replication, telemetry,
+            control)
+    key = _value_key((kind,) + args)
+    if key is not None:
+        with _PROGRAMS_LOCK:
+            if key in _PROGRAMS:
+                _PROGRAMS.move_to_end(key)
+                return _PROGRAMS[key]
+    run = _build_run(*args)
+    if kind == "sweep":
+        run = jax.vmap(jax.vmap(jax.vmap(run, (None, None, 0)),
+                                (None, 0, None)), (0, None, None))
+    program = jax.jit(run)
+    if key is not None:
+        with _PROGRAMS_LOCK:
+            _PROGRAMS[key] = program
+            if len(_PROGRAMS) > _PROGRAMS_MAX:
+                _PROGRAMS.popitem(last=False)
+    return program
+
+
+def clear_program_cache() -> None:
+    """Drop every kept dense program (for tests that count traces)."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.clear()
+
+
 def _fleet_engaged(fleet, policy, cfg, scenario, placement, replication,
                    telemetry, control=None) -> bool:
     """Resolve the ``fleet=`` seam shared by simulate/sweep.
@@ -412,6 +488,11 @@ def simulate(policy: PolicyLike, cfg: SimConfig, lam_total: float,
     configurations at >= 1024 servers, ``True``/`FleetConfig` forces it
     (raising when the configuration has no fleet step), ``False`` pins
     the dense path.
+
+    The dense program is kept per configuration under the same key as
+    `sweep`'s, so a repeated call with a new ``lam_total``, ``est`` or
+    ``seed`` is not traced again; an argument with no value key gets a
+    program built fresh.
     """
     if lam_total < 0:
         raise ValueError(f"lam_total must be >= 0, got {lam_total}")
@@ -420,8 +501,8 @@ def simulate(policy: PolicyLike, cfg: SimConfig, lam_total: float,
         from repro.sharding import sim as fleet_sim
         return fleet_sim.fleet_simulate(policy, cfg, lam_total, est, seed,
                                         fleet)
-    run = jax.jit(_build_run(policy, cfg, scenario, placement, replication,
-                             telemetry, control))
+    run = _program("simulate", policy, cfg, scenario, placement,
+                   replication, telemetry, control)
     out = run(jnp.float32(lam_total), jnp.asarray(est, jnp.float32),
               jnp.asarray(seed, jnp.uint32))
     res: Dict[str, Any] = {}
@@ -449,6 +530,15 @@ def sweep(policy: PolicyLike, cfg: SimConfig, lam_grid: np.ndarray,
     carry).  Telemetry metrics batch like everything else: scalars
     (delay_p50/p95/p99) come back (L, E, S), histograms (L, E, S, bins+1),
     the series (L, E, S, T_s, n_tracks).
+
+    The jitted program is kept per configuration (the 32 most recently
+    used): the key is the value of ``policy``, ``cfg``, ``scenario``,
+    ``placement``, ``replication``, ``telemetry`` and ``control``, each
+    part with its type.  A repeated call with new loads, estimates or
+    seeds of the same shapes neither traces, lowers nor loads the
+    program again.  An argument with no value key, such as a
+    `PolicyConfig` whose options are a dict, an array or a policy
+    instance, gets a program built and jitted fresh on every call.
     """
     if np.any(np.asarray(lam_grid) < 0):
         raise ValueError(f"lam_grid must be >= 0, got {lam_grid}")
@@ -457,14 +547,12 @@ def sweep(policy: PolicyLike, cfg: SimConfig, lam_grid: np.ndarray,
         from repro.sharding import sim as fleet_sim
         return fleet_sim.fleet_sweep(policy, cfg, lam_grid, est_stack,
                                      seeds, fleet)
-    # sim.prepare: build, trace, lower, compile or read the cache, enqueue;
-    # sim.fetch: wait for the device and copy the metrics to the host
+    # sim.prepare: look up the kept program (on a miss: build, trace, lower,
+    # compile or read the cache), enqueue; sim.fetch: wait for the device
+    # and copy the metrics to the host
     with maybe_span(None, "sim.prepare"):
-        run = _build_run(policy, cfg, scenario, placement, replication,
-                         telemetry, control)
-        f = jax.vmap(jax.vmap(jax.vmap(run, (None, None, 0)),
-                              (None, 0, None)), (0, None, None))
-        f = jax.jit(f)
+        f = _program("sweep", policy, cfg, scenario, placement, replication,
+                     telemetry, control)
         out = f(jnp.asarray(lam_grid, jnp.float32),
                 jnp.asarray(est_stack, jnp.float32),
                 jnp.asarray(seeds, jnp.uint32))

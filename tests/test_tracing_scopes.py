@@ -7,7 +7,9 @@ trace.  See docs/observability.md, "Profiler spans and scopes".
   routing and service under `sim.arrivals`, `sim.route` and `sim.serve`;
   the fleet chunk (segment-min routing) adds `sim.private` and `sim.fill`;
 * a profiler trace of one `sweep` holds `sim.prepare`, the one
-  `sim.trace` of its program inside it, then `sim.fetch`;
+  `sim.trace` of its program inside it, then `sim.fetch`; a repeated
+  `sweep` of the same configuration reuses its program and opens no
+  `sim.trace`;
 * the span helper annotates the profiler trace with and without an
   `EventRecorder`, and still fills the recorder's ring.
 
@@ -102,6 +104,7 @@ def _named(events, name):
 def test_sweep_host_spans(tmp_path):
     cfg = _cfg()
     est = np.stack([sim.make_estimates(cfg, "network", 0.0, -1)])
+    sim.clear_program_cache()   # no earlier test's program for this key
     with jax.profiler.trace(str(tmp_path)):
         out = sim.sweep("balanced_pandas", cfg, np.asarray([4.0]), est,
                         np.arange(2, dtype=np.uint32))
@@ -113,6 +116,24 @@ def test_sweep_host_spans(tmp_path):
     # one program, traced once, inside the preparation; then the fetch
     assert len(traces) == 1
     assert prep[0] <= traces[0][0] and traces[0][1] <= prep[1]
+    assert prep[1] <= fetch[0]
+
+
+def test_repeated_sweep_host_spans(tmp_path):
+    """`sweep` keeps its program per configuration: a second call of the
+    same configuration, with new seeds, opens no `sim.trace` span."""
+    cfg = _cfg()
+    est = np.stack([sim.make_estimates(cfg, "network", 0.0, -1)])
+    sim.sweep("balanced_pandas", cfg, np.asarray([4.0]), est,
+              np.arange(2, dtype=np.uint32))
+    with jax.profiler.trace(str(tmp_path)):
+        out = sim.sweep("balanced_pandas", cfg, np.asarray([4.0]), est,
+                        np.arange(2, 4, dtype=np.uint32))
+    assert out["mean_n"].shape == (1, 1, 2)
+    events = _host_events(tmp_path)
+    (prep,), (fetch,) = _named(events, "sim.prepare"), _named(events,
+                                                             "sim.fetch")
+    assert _named(events, "sim.trace") == []
     assert prep[1] <= fetch[0]
 
 
