@@ -499,8 +499,9 @@ def simulate(policy: PolicyLike, cfg: SimConfig, lam_total: float,
     if _fleet_engaged(fleet, policy, cfg, scenario, placement, replication,
                       telemetry, control):
         from repro.sharding import sim as fleet_sim
-        return fleet_sim.fleet_simulate(policy, cfg, lam_total, est, seed,
-                                        fleet)
+        fleet_cfg, weights = fleet_sim.stationary_traffic(cfg, scenario)
+        return fleet_sim.fleet_simulate(policy, fleet_cfg, lam_total, est,
+                                        seed, fleet, rack_weights=weights)
     run = _program("simulate", policy, cfg, scenario, placement,
                    replication, telemetry, control)
     out = run(jnp.float32(lam_total), jnp.asarray(est, jnp.float32),
@@ -545,8 +546,9 @@ def sweep(policy: PolicyLike, cfg: SimConfig, lam_grid: np.ndarray,
     if _fleet_engaged(fleet, policy, cfg, scenario, placement, replication,
                       telemetry, control):
         from repro.sharding import sim as fleet_sim
-        return fleet_sim.fleet_sweep(policy, cfg, lam_grid, est_stack,
-                                     seeds, fleet)
+        fleet_cfg, weights = fleet_sim.stationary_traffic(cfg, scenario)
+        return fleet_sim.fleet_sweep(policy, fleet_cfg, lam_grid, est_stack,
+                                     seeds, fleet, rack_weights=weights)
     # sim.prepare: look up the kept program (on a miss: build, trace, lower,
     # compile or read the cache), enqueue; sim.fetch: wait for the device
     # and copy the metrics to the host

@@ -54,10 +54,12 @@ What changes (and what is pinned to hold still):
 
 Service/scheduling dynamics reuse `core.balanced_pandas.serve_and_schedule`
 verbatim (vectorized already).  Supported configurations: policies
-`balanced_pandas` / `pandas_po2`, static scenario, uniform placement,
-static replication, no telemetry — `fleet_supported` reports why anything
-else must take the dense path, and `core.simulator.simulate/sweep` fall
-back (or raise, when ``fleet=True`` was explicit).
+`balanced_pandas` / `pandas_po2`, a stationary scenario (one segment that
+may set the hot fraction and per-rack arrival weights — ``static``,
+``hot_racks`` — see `stationary_traffic`), uniform placement, static
+replication, no telemetry — `fleet_supported` reports why anything else
+must take the dense path, and `core.simulator.simulate/sweep` fall back
+(or raise, when ``fleet=True`` was explicit).
 """
 
 from __future__ import annotations
@@ -140,10 +142,35 @@ class FleetCtx:
     hot_rack_size: int              # rack 0 size (M for a depth-0 fleet)
     anc: Any                        # (depth, M) int32 device array
     gids: Tuple[Any, ...]           # per-level (M,) group-id rows
+    # per-rack arrival weights (None: hot tasks live in rack 0)
+    rack_cum: Any = None            # (R,) f32 cumulative weights, see
+    #                                 `cumulative_weights`
+    rack_start: Any = None          # (R,) int32 first server of each rack
+    rack_size: Any = None           # (R,) int32 servers in each rack
 
 
-def make_ctx(topo: loc.Topology) -> FleetCtx:
+def cumulative_weights(rack_weights) -> np.ndarray:
+    """(R,) float32 cumulative shares of the per-rack weights: the
+    running sums in float64 over the last of them, rounded once to
+    float32, and 1.0 from the last rack of positive weight on.  A hot
+    task's rack is the number of entries at or below a uniform draw in
+    [0, 1), so a rack of weight 0 is never drawn."""
+    w = np.asarray(rack_weights, np.float64)
+    cum = np.cumsum(w)
+    cum = cum / cum[-1]
+    cum[np.flatnonzero(w > 0)[-1]:] = 1.0
+    return cum.astype(np.float32)
+
+
+def make_ctx(topo: loc.Topology, rack_weights=None) -> FleetCtx:
     anc = jnp.asarray(topo.ancestors, jnp.int32)
+    racks = {}
+    if rack_weights is not None:
+        sizes = np.asarray(topo.group_sizes[0] if topo.depth
+                           else (topo.num_servers,), np.int32)
+        racks = dict(rack_cum=jnp.asarray(cumulative_weights(rack_weights)),
+                     rack_start=jnp.asarray(np.cumsum(sizes) - sizes),
+                     rack_size=jnp.asarray(sizes))
     return FleetCtx(
         num_servers=topo.num_servers,
         num_tiers=topo.num_tiers,
@@ -154,7 +181,54 @@ def make_ctx(topo: loc.Topology) -> FleetCtx:
                        else topo.num_servers),
         anc=anc,
         gids=tuple(anc[l] for l in range(topo.depth)),
+        **racks,
     )
+
+
+def stationary_traffic(cfg, scenario=None):
+    """The arrival law the fleet path runs for `scenario`:
+    ``(cfg, rack_weights)``, with the scenario's hot fraction in ``cfg``
+    and its per-rack weights resized to the rack count (a tuple of R
+    floats), or None where hot tasks live in rack 0.
+
+    Only a stationary scenario has one: a single segment with no
+    arrival-rate, tier or per-server rate multiplier and no failures.
+    Raises ValueError naming what else the scenario asks for."""
+    from repro import workloads as wl
+    scn = wl.make_scenario(scenario)
+    if len(scn.segments) != 1:
+        raise ValueError(f"scenario {scn.name!r} has {len(scn.segments)} "
+                         f"segments; only a stationary scenario (one "
+                         f"segment) is fleet-compiled")
+    sched = wl.compile_schedule(scn, cfg.topo, cfg.horizon, cfg.p_hot)
+    if np.asarray(sched.lam_mult)[0] != 1.0:
+        raise ValueError(f"scenario {scn.name!r} scales the arrival rate; "
+                         f"the fleet path runs the configured rate")
+    if not np.all(np.asarray(sched.rate_mult) == 1.0):
+        raise ValueError(f"scenario {scn.name!r} changes true rates "
+                         f"(tier_mult / slow_servers); the fleet step "
+                         f"serves at the configured rates")
+    if sched.alive is not None:
+        raise ValueError(f"scenario {scn.name!r} has a failure track, which "
+                         f"rides the dense replication machinery")
+    seg = scn.segments[0]
+    if seg.p_hot is not None:
+        cfg = dataclasses.replace(cfg, p_hot=seg.p_hot)
+    if sched.rack_weights is None:
+        if seg.hot_rack % cfg.topo.num_racks:
+            raise ValueError(f"scenario {scn.name!r} puts hot traffic on rack "
+                             f"{seg.hot_rack}; a hot rack other than 0 "
+                             f"reaches the fleet path only as rack_weights")
+        return cfg, None
+    weights = tuple(float(x) for x in np.asarray(sched.rack_weights)[0])
+    sizes = (cfg.topo.group_sizes[0] if cfg.topo.depth
+             else (cfg.topo.num_servers,))
+    small = [r for r, (w, n) in enumerate(zip(weights, sizes))
+             if w > 0 and n < loc.NUM_REPLICAS]
+    if small:
+        raise ValueError(f"racks {small[:5]} carry hot weight but hold fewer "
+                         f"than {loc.NUM_REPLICAS} servers")
+    return cfg, weights
 
 
 def fleet_supported(policy_like: PolicyLike, cfg, scenario=None,
@@ -168,9 +242,10 @@ def fleet_supported(policy_like: PolicyLike, cfg, scenario=None,
                 f"(supported: {_SUPPORTED_POLICIES})")
     if telemetry is not None and telemetry is not False:
         return "telemetry recorders require the dense in-scan step"
-    from repro import workloads as wl
-    if wl.make_scenario(scenario).name != "static":
-        return "only the static scenario is fleet-compiled"
+    try:
+        stationary_traffic(cfg, scenario)
+    except ValueError as e:
+        return str(e)
     from repro.placement import make_placement
     if make_placement(placement).name != "uniform":
         return "only uniform placement has a fleet sampler"
@@ -187,19 +262,55 @@ def fleet_supported(policy_like: PolicyLike, cfg, scenario=None,
 # ---------------------------------------------------------------------------
 
 
+def _arrival_keys(key: jax.Array, ctx: FleetCtx):
+    """(k_n, k_hot, k_rack, k_u) of one slot's arrivals: ``key`` splits
+    into the count's key k_n and k_t; k_t splits into k_hot and k_u, or,
+    with per-rack weights, into k_hot, k_rack and k_u (k_rack None
+    without weights)."""
+    k_n, k_t = jax.random.split(key)
+    if ctx.rack_cum is None:
+        k_hot, k_u = jax.random.split(k_t)
+        return k_n, k_hot, None, k_u
+    k_hot, k_rack, k_u = jax.random.split(k_t, 3)
+    return k_n, k_hot, k_rack, k_u
+
+
+def _hot_lanes(key: jax.Array, ctx: FleetCtx, p_hot: float, batch: int):
+    """(B,) bool: the lanes whose task is hot, drawn from k_hot."""
+    return jax.random.bernoulli(_arrival_keys(key, ctx)[1], p_hot, (batch,))
+
+
 def _sample_arrivals(key: jax.Array, ctx: FleetCtx, lam, p_hot: float,
                      batch: int):
     """(types (B,3) i32 sorted, active (B,) bool) — same arrival law as
-    `locality.sample_arrivals_at` under the static scenario (truncated
-    Poisson count; hot tasks replica-set inside rack 0, the rest uniform)
-    in O(B) work instead of (B, M) Gumbels."""
-    k_n, k_t = jax.random.split(key)
+    `locality.sample_arrivals_at` under a stationary scenario (truncated
+    Poisson count; hot tasks replica-set inside one rack, the rest
+    uniform) in O(B) work instead of (B, M) Gumbels.
+
+    Key discipline (`_arrival_keys`): n = min(poisson(k_n, lam), B) and
+    lanes below n are active; hot = bernoulli(k_hot, p_hot, (B,)).
+    Without rack weights a hot task's pool is rack 0.  With them, its
+    rack is the number of `ctx.rack_cum` entries at or below
+    uniform(k_rack, (B,)), and its pool is that rack's servers, counted
+    from the rack's first server.  A cold task's pool is the fleet.
+    Within a pool of S servers, u = uniform(k_u, (B, 3)) picks three
+    distinct offsets: x0 = floor(u0 S), x1 = floor(u1 (S-1)) skipping
+    x0, x2 = floor(u2 (S-2)) skipping both, each clipped below its
+    range's end.
+    """
+    k_n, _, k_rack, k_u = _arrival_keys(key, ctx)
     n = jnp.minimum(jax.random.poisson(k_n, lam), batch)
     active = jnp.arange(batch) < n
-    k_hot, k_u = jax.random.split(k_t)
-    hot = jax.random.bernoulli(k_hot, p_hot, (batch,))
-    size = jnp.where(hot, ctx.hot_rack_size, ctx.num_servers
-                     ).astype(jnp.float32)
+    hot = _hot_lanes(key, ctx, p_hot, batch)
+    if ctx.rack_cum is None:
+        size = jnp.where(hot, ctx.hot_rack_size, ctx.num_servers
+                         ).astype(jnp.float32)
+    else:
+        rack = jnp.searchsorted(ctx.rack_cum,
+                                jax.random.uniform(k_rack, (batch,)),
+                                side="right")
+        size = jnp.where(hot, ctx.rack_size[rack], ctx.num_servers
+                         ).astype(jnp.float32)
     r = jax.random.uniform(k_u, (batch, 3))
     x0 = jnp.minimum(jnp.floor(r[:, 0] * size), size - 1)
     x1 = jnp.minimum(jnp.floor(r[:, 1] * (size - 1)), size - 2)
@@ -209,6 +320,8 @@ def _sample_arrivals(key: jax.Array, ctx: FleetCtx, lam, p_hot: float,
     x2 = x2 + (x2 >= lo)
     x2 = x2 + (x2 >= hi)
     types = jnp.stack([x0, x1, x2], axis=1).astype(jnp.int32)
+    if ctx.rack_cum is not None:
+        types = types + jnp.where(hot, ctx.rack_start[rack], 0)[:, None]
     return jnp.sort(types, axis=1), active
 
 
@@ -401,13 +514,46 @@ def _route_batch_po2(s: bp.PandasState, est, ctx: FleetCtx, locs, active,
 # ---------------------------------------------------------------------------
 
 
-def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
+class FleetCarry(tuple):
+    """The chunk program's carry: (q (M,K) i32, serving (M,) i32, mean_n
+    f32, n_meas f32, completions i32), which it unpacks, indexes and
+    iterates as, plus two counters over the measured slots by name:
+    `hot_arrived` (hot tasks that arrived) and `pool_placed` (tasks
+    routed to the remote tier: for Balanced-PANDAS, those the pool's
+    water-fill placed), both i32."""
+
+    def __new__(cls, core, hot_arrived, pool_placed):
+        self = super().__new__(cls, core)
+        self.hot_arrived, self.pool_placed = hot_arrived, pool_placed
+        return self
+
+    @classmethod
+    def of(cls, carry) -> "FleetCarry":
+        """A FleetCarry as it is; a plain 5-tuple with counters at 0; a
+        7-tuple with its last two as the counters."""
+        if isinstance(carry, cls):
+            return carry
+        carry = tuple(carry)
+        if len(carry) == 5:
+            return cls(carry, jnp.int32(0), jnp.int32(0))
+        return cls(carry[:5], *carry[5:])
+
+
+jax.tree_util.register_pytree_node(
+    FleetCarry, lambda c: (tuple(c) + (c.hot_arrived, c.pool_placed), None),
+    lambda _, leaves: FleetCarry(leaves[:5], *leaves[5:]))
+
+
+def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig,
+                       rack_weights=None):
     """Returns (init() -> carry, chunk(carry, t0, lam, est, seed) -> carry).
 
-    carry = (q (M,K) i32, serving (M,) i32, mean_n f32, n_meas f32,
-    completions i32).  `chunk` advances `fc.chunk` slots starting at slot
-    t0; slots at t >= horizon are frozen (the carry re-selected), so the
-    tail chunk reuses the same compiled program.  Jit it with
+    The carry is a `FleetCarry`; `chunk` also takes a plain 5-tuple (its
+    counters start at 0) or 7-tuple.  `chunk` advances `fc.chunk` slots
+    starting at slot t0; slots at t >= horizon are frozen (the carry
+    re-selected), so the tail chunk reuses the same compiled program.
+    `rack_weights` (R floats, `stationary_traffic`) spread hot tasks
+    over racks; None keeps them in rack 0.  Jit it with
     ``donate_argnums=0`` and drive the horizon from a Python loop.
     """
     policy = make_policy(policy_like)
@@ -415,7 +561,7 @@ def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
         raise ValueError(f"policy {policy.name!r} has no fleet step "
                          f"(supported: {_SUPPORTED_POLICIES})")
     d_choices = int(getattr(policy, "d", 0))
-    ctx = make_ctx(cfg.topo)
+    ctx = make_ctx(cfg.topo, rack_weights)
     m, k = ctx.num_servers, ctx.num_tiers
     batch = cfg.max_arrivals
     true_k = cfg.true_rates.as_array()
@@ -424,13 +570,15 @@ def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
     use_pallas = kops._on_tpu() if fc.use_pallas is None else fc.use_pallas
 
     def init():
-        return (jnp.zeros((m, k), jnp.int32), jnp.zeros((m,), jnp.int32),
-                jnp.float32(0.0), jnp.float32(0.0), jnp.int32(0))
+        return FleetCarry((jnp.zeros((m, k), jnp.int32),
+                           jnp.zeros((m,), jnp.int32), jnp.float32(0.0),
+                           jnp.float32(0.0), jnp.int32(0)),
+                          jnp.int32(0), jnp.int32(0))
 
     def chunk(carry, t0, lam, est, seed):
         # runs only while JAX traces the program (see core/simulator)
         with maybe_span(None, "sim.trace"):
-            return chunk_body(carry, t0, lam, est, seed)
+            return chunk_body(FleetCarry.of(carry), t0, lam, est, seed)
 
     def chunk_body(carry, t0, lam, est, seed):
         base_key = jax.random.PRNGKey(seed)
@@ -443,6 +591,9 @@ def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
             with jax.named_scope("sim.arrivals"):
                 types, active = _sample_arrivals(k_arr, ctx, lam, p_hot,
                                                  batch)
+                hot_n = jnp.sum(_hot_lanes(k_arr, ctx, p_hot, batch)
+                                & active, dtype=jnp.int32)
+            remote_before = jnp.sum(s.q[:, k - 1])
             k_route, k_serve = jax.random.split(k_algo)
             with jax.named_scope("sim.route"):
                 if policy.name == "pandas_po2":
@@ -451,6 +602,7 @@ def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
                 else:
                     s = _route_batch_pandas(s, est, ctx, types, active, fc,
                                             use_pallas)
+            pool_n = jnp.sum(s.q[:, k - 1]) - remote_before
             with jax.named_scope("sim.serve"):
                 s, compl_t = bp.serve_and_schedule(s, k_serve, true_k)
             n = (jnp.sum(s.q) + jnp.sum(s.serving > 0)).astype(jnp.float32)
@@ -458,9 +610,12 @@ def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
             n_meas2 = n_meas + in_w
             mean_n2 = mean_n + in_w * (n - mean_n) / jnp.maximum(n_meas2, 1.0)
             compl2 = compl + compl_t * (t >= warmup)
-            new = (s.q, s.serving, mean_n2, n_meas2, compl2)
+            new = FleetCarry((s.q, s.serving, mean_n2, n_meas2, compl2),
+                             c.hot_arrived + hot_n * (t >= warmup),
+                             c.pool_placed + pool_n * (t >= warmup))
             live = t < horizon
-            return tuple(jnp.where(live, a, b) for a, b in zip(new, c)), ()
+            return jax.tree.map(lambda a, b: jnp.where(live, a, b), new,
+                                c), ()
 
         carry, _ = jax.lax.scan(step, carry, t0 + jnp.arange(fc.chunk),
                                 unroll=fc.unroll)
@@ -469,60 +624,75 @@ def _build_fleet_chunk(policy_like: PolicyLike, cfg, fc: FleetConfig):
     return init, chunk
 
 
-def _finalize(carry_np, lam_total) -> Dict[str, Any]:
-    """Metrics dict (same keys as the dense path) from a final carry."""
+def _finalize(carry_np: FleetCarry, lam_total) -> Dict[str, Any]:
+    """Metrics dict (the dense path's keys, plus `hot_share` and
+    `pool_share`: hot arrivals and pool placements per task offered over
+    the measured slots) from a final carry on the host."""
     q, serving, mean_n, n_meas, compl = carry_np
-    denom = np.float32(lam_total)  # static scenario: lam_scale == 1
+    denom = np.float32(lam_total)  # stationary scenario: lam_scale == 1
     mean_delay = np.where(denom > 0, mean_n / denom, np.nan)
+    offered = denom * n_meas
+    per_offered = np.where(offered > 0, offered, 1.0)
     return {
         "mean_n": mean_n,
         "mean_delay": mean_delay,
         "throughput": compl / np.maximum(n_meas, 1.0),
         "final_n": (q.sum(axis=(-2, -1))
                     + (serving > 0).sum(axis=-1)).astype(np.float32),
+        "hot_share": np.where(offered > 0,
+                              carry_np.hot_arrived / per_offered, np.nan),
+        "pool_share": np.where(offered > 0,
+                               carry_np.pool_placed / per_offered, np.nan),
     }
 
 
 # Keyed cache of jitted chunk closures: repeated fleet_simulate calls
-# with the same (policy, cfg, fleet) settings — a seed study, the test
-# suite's band runs — would otherwise retrace AND recompile every call,
-# and the fleet chunk compile is ~8 s at M=10008 on one core.  The key
-# is the dataclass reprs (all three are frozen value types), so a config
-# change can never alias a stale program.
-_CHUNK_CACHE: Dict[Tuple[str, str, str], Any] = {}
+# with the same (policy, cfg, fleet, rack weights) settings — a seed
+# study, the test suite's band runs — would otherwise retrace AND
+# recompile every call, and the fleet chunk compile is ~8 s at M=10008 on
+# one core.  The key is the dataclass reprs (all three are frozen value
+# types; cfg holds p_hot) and the weights' values, so a config change can
+# never alias a stale program.
+_CHUNK_CACHE: Dict[Tuple[Any, ...], Any] = {}
 
 
-def _jitted_chunk(policy: PolicyLike, cfg, fc: FleetConfig):
-    key = (repr(policy), repr(cfg), repr(fc))
+def _jitted_chunk(policy: PolicyLike, cfg, fc: FleetConfig,
+                  rack_weights=None):
+    key = (repr(policy), repr(cfg), repr(fc),
+           None if rack_weights is None else tuple(rack_weights))
     hit = _CHUNK_CACHE.get(key)
     if hit is None:
-        init, chunk = _build_fleet_chunk(policy, cfg, fc)
+        init, chunk = _build_fleet_chunk(policy, cfg, fc, rack_weights)
         hit = (init, jax.jit(chunk, donate_argnums=0))
         _CHUNK_CACHE[key] = hit
     return hit
 
 
 def fleet_simulate(policy: PolicyLike, cfg, lam_total: float, est,
-                   seed: int = 0,
-                   fleet: FleetLike = None) -> Dict[str, Any]:
-    """Fleet-path analogue of `core.simulator.simulate` (static scenario,
-    uniform placement).  Same metrics keys; scalars come back as floats."""
+                   seed: int = 0, fleet: FleetLike = None,
+                   rack_weights=None) -> Dict[str, Any]:
+    """Fleet-path analogue of `core.simulator.simulate` (stationary
+    scenario, uniform placement): `rack_weights` as `stationary_traffic`
+    gives them.  Same metrics keys plus `hot_share` and `pool_share`;
+    scalars come back as floats."""
     if lam_total < 0:
         raise ValueError(f"lam_total must be >= 0, got {lam_total}")
     fc = as_fleet_config(fleet)
-    init, fn = _jitted_chunk(policy, cfg, fc)
+    init, fn = _jitted_chunk(policy, cfg, fc, rack_weights)
     carry = init()
     lam = jnp.float32(lam_total)
     est = jnp.asarray(est, jnp.float32)
     seed = jnp.asarray(seed, jnp.uint32)
     for ci in range(-(-cfg.horizon // fc.chunk)):
         carry = fn(carry, jnp.int32(ci * fc.chunk), lam, est, seed)
-    out = _finalize(tuple(np.asarray(x) for x in carry), lam_total)
+    out = _finalize(jax.tree.map(np.asarray, FleetCarry.of(carry)),
+                    lam_total)
     return {k: float(v) for k, v in out.items()}
 
 
 def fleet_sweep(policy: PolicyLike, cfg, lam_grid, est_stack, seeds,
-                fleet: FleetLike = None) -> Dict[str, np.ndarray]:
+                fleet: FleetLike = None,
+                rack_weights=None) -> Dict[str, np.ndarray]:
     """Fleet-path analogue of `core.simulator.sweep`: (L, E, S) metrics.
 
     The (load x error x seed) grid is flattened and vmapped through the
@@ -533,7 +703,7 @@ def fleet_sweep(policy: PolicyLike, cfg, lam_grid, est_stack, seeds,
     if np.any(lam_grid < 0):
         raise ValueError(f"lam_grid must be >= 0, got {lam_grid}")
     fc = as_fleet_config(fleet)
-    init, chunk = _build_fleet_chunk(policy, cfg, fc)
+    init, chunk = _build_fleet_chunk(policy, cfg, fc, rack_weights)
     nl, ne, ns = len(lam_grid), len(est_stack), len(seeds)
     n = nl * ne * ns
     lam_b = jnp.asarray(np.repeat(lam_grid, ne * ns))
@@ -545,6 +715,6 @@ def fleet_sweep(policy: PolicyLike, cfg, lam_grid, est_stack, seeds,
         lambda a: jnp.broadcast_to(a, (n,) + a.shape), init())
     for ci in range(-(-cfg.horizon // fc.chunk)):
         carry = fn(carry, jnp.int32(ci * fc.chunk), lam_b, est_b, seed_b)
-    out = _finalize(tuple(np.asarray(x) for x in carry),
+    out = _finalize(jax.tree.map(np.asarray, FleetCarry.of(carry)),
                     np.asarray(lam_b))
     return {k: np.asarray(v).reshape(nl, ne, ns) for k, v in out.items()}

@@ -111,6 +111,19 @@ def hot_shift(phases: int = 4, p_hot: Optional[float] = None) -> Scenario:
     return Scenario("hot_shift", segs)
 
 
+@register_scenario("hot_racks")
+def hot_racks(weights: Sequence[float] = (1.0, 0.0, 0.0, 0.0),
+              p_hot: Optional[float] = None) -> Scenario:
+    """Stationary per-rack hot traffic: each hot task draws its rack from
+    `weights` (cycled over the rack count at compile, so the default makes
+    every 4th rack hot — the paper's 1 hot rack in 4, tiled over a large
+    fleet) and takes all its replicas inside it, optionally overriding
+    the hot fraction.  One segment, so the fleet path runs it too."""
+    return Scenario("hot_racks", (Segment(start=0.0,
+                                          rack_weights=tuple(weights),
+                                          p_hot=p_hot),))
+
+
 @register_scenario("stragglers")
 def stragglers(servers: Sequence[int] = (0, 1), factor: float = 0.25,
                start: float = 0.25, width: float = 0.5) -> Scenario:

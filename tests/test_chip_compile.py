@@ -24,7 +24,8 @@ import jax.numpy as jnp
 
 from repro.core import locality as loc, simulator as sim
 from repro.kernels import ops as kops
-from repro.sharding.sim import FleetConfig, _build_fleet_chunk, make_ctx
+from repro.sharding.sim import (FleetConfig, _build_fleet_chunk, make_ctx,
+                                stationary_traffic)
 
 FLEET_M = 10_008
 
@@ -100,6 +101,27 @@ def test_fleet_chunk_compiles_with_kernel(one_chip, monkeypatch):
     est = loc.per_server_rates(rates.as_array(), FLEET_M).astype(np.float32)
     init, chunk = _build_fleet_chunk("balanced_pandas", cfg, FleetConfig())
     args = (tuple(_shape(a, one_chip) for a in init()),
+            _shape(np.int32(0), one_chip), _shape(np.float32(lam), one_chip),
+            _shape(est, one_chip), _shape(np.uint32(0), one_chip))
+    text = jax.jit(chunk).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_fleet_chunk_compiles_with_hot_racks(one_chip, monkeypatch):
+    """The chunk of the hot-rack fleet cell: a quarter of the racks hot,
+    half the tasks hot, 0.9 of the fluid capacity (417 x the paper's
+    10.0 tasks/slot), lanes 2.05 x that, the weighted sampler inside."""
+    monkeypatch.setattr(kops, "_on_tpu", lambda: True)
+    rates = loc.Rates()
+    lam = 0.9 * 417 * loc.capacity_hot_rack(loc.Topology(24, 6), rates, 0.5)
+    cfg = sim.SimConfig(topo=loc.Topology(FLEET_M, 6), true_rates=rates,
+                        p_hot=0.5, max_arrivals=int(2.05 * lam),
+                        horizon=2048, warmup=512)
+    cfg, weights = stationary_traffic(cfg, "hot_racks")
+    est = loc.per_server_rates(rates.as_array(), FLEET_M).astype(np.float32)
+    init, chunk = _build_fleet_chunk("balanced_pandas", cfg, FleetConfig(),
+                                     weights)
+    args = (jax.tree.map(lambda a: _shape(a, one_chip), init()),
             _shape(np.int32(0), one_chip), _shape(np.float32(lam), one_chip),
             _shape(est, one_chip), _shape(np.uint32(0), one_chip))
     text = jax.jit(chunk).lower(*args).compile().as_text()
