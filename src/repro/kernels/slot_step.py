@@ -9,21 +9,33 @@ slot).  This kernel fuses the private phase with the workload computation
 it consumes:
 
     W_m     = sum_k q[m, k] / est[m, k]  (+ in-service residual)
-    score   = W_m / est[m, tier(m, task)] - est[...] * 1e-6
+    s_t[m]  = W_m / est[m, t] - est[m, t] * 1e-6      for tiers t < K-1
+    score   = s_{tier(m, task)}[m]
     out_b   = argmin over servers with tier(m, task) < K-1
 
 so one kernel launch scores the whole (B, M) surface tile by tile: tasks
-on sublanes, servers on lanes.  Per-server inputs arrive transposed,
-one row per tier or level ((K, M) queues and rates, (1, M) serving,
-(D, M) ancestors), so every per-tier value is a static row slice, and the
-tier of each (task, server) pair is derived by an unrolled `jnp.where`
-chain over the levels — the kernel body holds no gather.  Per-task
+on sublanes, servers on lanes.  Per-server inputs arrive transposed, one
+row per tier or level ((K, M) queues and rates, (1, M) serving, (D, M)
+ancestors), so every per-tier value is a static row slice.  What depends
+on one axis is computed on that axis alone: the workload and the
+per-tier score rows s_t once per server block, (1, bm) each; the task's
+locals shifted into block coordinates once a grid step, (bt, 3), not per
+pair.  The (bt, bm) surface then holds only compares, selects and
+minima: each pair's tier tests (an unrolled chain over the levels,
+deepest first, then the locals) select its score from the rows, starting
+from the remote tier's +LARGE, and the block folds into lane-dense
+running minima, (bt, 128), (score, lowest server index) held in VMEM
+scratch across the server axis.  The cross-lane minima, the tier at the
+argmin and every (bt, 1) column run once per task block, at its last
+server block: on the chip those per-row operations cost a grid step more
+than the surface does.  The kernel body holds no gather.  Per-task
 results are (B, 1) columns.  Compare `wwl_route.py`, which scores ALL
 servers (including the remote tier) against a precomputed workload
-vector: the fused kernel reads the raw policy state (q, serving) instead,
-and masks the remote tier out, because the fleet path assigns remote
-traffic by water-filling rather than per-task argmin (B tasks hitting the
-same remote argmin would pile onto one server — see docs/scaling.md).
+vector: the fused kernel reads the raw policy state (q, serving)
+instead, and masks the remote tier out, because the fleet path assigns
+remote traffic by water-filling rather than per-task argmin (B tasks
+hitting the same remote argmin would pile onto one server — see
+docs/scaling.md).
 
 The ``- rate * 1e-6`` term is the same infinitesimal faster-tier
 preference the sequential simulator applies on exact workload ties
@@ -50,8 +62,8 @@ LARGE = 3.0e38  # +inf surrogate inside min-accumulators (matches wwl_route)
 
 
 def _fleet_route_kernel(q_ref, rates_ref, serving_ref, anc_ref, tasks_ref,
-                        score_ref, server_ref, tier_ref, *,
-                        block_m: int, depth: int):
+                        score_ref, server_ref, tier_ref, min_acc, idx_acc,
+                        *anc_acc, block_m: int, depth: int):
     """One (task-block, server-block) tile.
 
     q_ref:       (K, bm)        f32   waiting tasks per (tier, server)
@@ -60,17 +72,23 @@ def _fleet_route_kernel(q_ref, rates_ref, serving_ref, anc_ref, tasks_ref,
     anc_ref:     (D, bm)        i32   ancestor table slice of this block
     tasks_ref:   (bt, 3(D+1))   i32   task locals, then their level-l
                                       ancestor groups, 3 columns per level
-    score_ref:   (bt, 1)        f32   running min private score (revisited)
-    server_ref:  (bt, 1)        i32   running argmin server     (revisited)
-    tier_ref:    (bt, 1)        i32   tier at argmin            (revisited)
+    score_ref:   (bt, 1)        f32   min private score      (last step)
+    server_ref:  (bt, 1)        i32   lowest-index argmin    (last step)
+    tier_ref:    (bt, 1)        i32   tier at the argmin     (last step)
+    min_acc:     (bt, 128)      f32   scratch: running min per lane
+    idx_acc:     (bt, 128)      i32   scratch: its lowest server index
+    anc_acc:     D-1 x (bt, 128) i32  scratch: that server's level-l
+                                      ancestor, l = 0..D-2
     """
     j = pl.program_id(1)
+    lanes = min_acc.shape[1]
 
     @pl.when(j == 0)
     def _init():
-        score_ref[...] = jnp.full_like(score_ref, LARGE)
-        server_ref[...] = jnp.zeros_like(server_ref)
-        tier_ref[...] = jnp.zeros_like(tier_ref)
+        min_acc[...] = jnp.full_like(min_acc, LARGE)
+        idx_acc[...] = jnp.zeros_like(idx_acc)
+        for acc in anc_acc:
+            acc[...] = jnp.zeros_like(acc)
 
     q = q_ref[...]
     rates = rates_ref[...]
@@ -90,43 +108,73 @@ def _fleet_route_kernel(q_ref, rates_ref, serving_ref, anc_ref, tasks_ref,
         resid_rate = jnp.where(resid_idx == t, row[t], resid_rate)
     w = w + jnp.where(serving > 0, 1.0 / resid_rate, 0.0)
 
+    # per-tier private scores, once per server block: a server's score at
+    # tier t depends on the server alone, so the (bt, bm) surface below
+    # only selects among these rows (same f32 ops, element for element)
+    s = [w / row[t] - row[t] * 1e-6 for t in range(depth + 1)]
+
     bt = tasks.shape[0]
     bm = w.shape[1]
     col = jax.lax.broadcasted_iota(jnp.int32, (bt, bm), 1)
-    sid = j * block_m + col
+    # the task's locals in this block's coordinates, (bt, 3) once a step
+    locs = tasks[:, 0:3] - j * block_m
 
-    def hits(ids, c):
-        """(bt, bm) mask: ids equal one of the task's columns c..c+2."""
-        return ((ids == tasks[:, c:c + 1]) | (ids == tasks[:, c + 1:c + 2])
-                | (ids == tasks[:, c + 2:c + 3]))
+    def hits(ids, cols):
+        """Mask: ids equal one of the three (bt, 1) columns of cols."""
+        return ((ids == cols[:, 0:1]) | (ids == cols[:, 1:2])
+                | (ids == cols[:, 2:3]))
 
-    # remote by default; sharpen tier/rate level by level, deepest first —
-    # the depth loop is unrolled at trace time (static shape)
-    tier = jnp.full((bt, bm), depth + 1, jnp.int32)
-    rate = jnp.broadcast_to(row[depth + 1], (bt, bm))
+    def groups(lvl):
+        return tasks[:, 3 * (lvl + 1):3 * (lvl + 2)]
+
+    # remote (pool-filled, masked out) by default; sharpen level by level,
+    # deepest first — the depth loop is unrolled at trace time (static shape)
+    score = jnp.full((bt, bm), LARGE, jnp.float32)
     for lvl in range(depth - 1, -1, -1):
-        share = hits(anc_ref[lvl:lvl + 1, :], 3 * (lvl + 1))
-        tier = jnp.where(share, lvl + 1, tier)
-        rate = jnp.where(share, row[lvl + 1], rate)
-    local = hits(sid, 0)
-    tier = jnp.where(local, 0, tier)
-    rate = jnp.where(local, row[0], rate)
-    score = jnp.broadcast_to(w, (bt, bm)) / rate - rate * 1e-6
-    # the private mask: the remote tier (K-1 = depth+1) is pool-filled
-    score = jnp.where(tier <= depth, score, LARGE)
+        score = jnp.where(hits(anc_ref[lvl:lvl + 1, :], groups(lvl)),
+                          s[lvl + 1], score)
+    score = jnp.where(hits(col, locs), s[0], score)
 
-    # lowest-index argmin and its tier as masked minima (no gather)
-    blk_min = jnp.min(score, axis=1, keepdims=True)                # (bt, 1)
-    blk_arg = jnp.min(jnp.where(score == blk_min, col, bm), axis=1,
-                      keepdims=True)
-    blk_tier = jnp.min(jnp.where(col == blk_arg, tier, depth + 1), axis=1,
-                       keepdims=True)
+    # Fold the block into the per-lane running minima, 128 lanes at a time
+    # in increasing server order; the strict < keeps each lane's lowest
+    # index among equal scores.  No lane crosses another here: the
+    # cross-lane minima and every (bt, 1) column run once, at the last step.
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bt, lanes), 1)
+    best, idx = min_acc[...], idx_acc[...]
+    anc_at = [acc[...] for acc in anc_acc]
+    for c in range(0, bm, lanes):
+        part = score[:, c:c + lanes]
+        better = part < best
+        best = jnp.where(better, part, best)
+        idx = jnp.where(better, j * block_m + c + lane, idx)
+        anc_at = [jnp.where(better, anc_ref[lvl:lvl + 1, c:c + lanes], a)
+                  for lvl, a in enumerate(anc_at)]
+    min_acc[...] = best
+    idx_acc[...] = idx
+    for acc, a in zip(anc_acc, anc_at):
+        acc[...] = a
 
-    best = score_ref[...]
-    better = blk_min < best                    # strict: keeps lowest index
-    score_ref[...] = jnp.where(better, blk_min, best)
-    server_ref[...] = jnp.where(better, j * block_m + blk_arg, server_ref[...])
-    tier_ref[...] = jnp.where(better, blk_tier, tier_ref[...])
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        # lowest index among the lanes that hold the minimum
+        none = jnp.iinfo(jnp.int32).max
+        low = jnp.min(best, axis=1, keepdims=True)                 # (bt, 1)
+        at = best == low
+        arg = jnp.min(jnp.where(at, idx, none), axis=1, keepdims=True)
+        found = low < LARGE
+        # Its tier: a found argmin is private, so tier 0 if local, else the
+        # shallowest level whose group holds it; the deepest private level
+        # needs no test, so only levels 0..depth-2 keep its ancestors.
+        tier = jnp.full((bt, 1), depth, jnp.int32)
+        at = at & (idx == arg)
+        for lvl in range(depth - 2, -1, -1):
+            anc = jnp.min(jnp.where(at, anc_at[lvl], none), axis=1,
+                          keepdims=True)
+            tier = jnp.where(hits(anc, groups(lvl)), lvl + 1, tier)
+        tier = jnp.where(hits(arg, tasks[:, 0:3]), 0, tier)
+        score_ref[...] = low
+        server_ref[...] = jnp.where(found, arg, 0)
+        tier_ref[...] = jnp.where(found, tier, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_tasks", "block_servers",
@@ -138,8 +186,9 @@ def fleet_route_pallas(q: jnp.ndarray, serving: jnp.ndarray,
     """Padded, tiled fused workload + private-route.  See ref.fleet_route.
 
     q (M, K) f32, serving (M,) i32, est_rates (M, K) f32, server_anc the
-    (depth, M) ancestor table.  Caller guarantees M % block_servers == 0
-    and B % block_tasks == 0 (ops.fleet_route pads; padding servers carry
+    (depth, M) ancestor table.  Caller guarantees M % block_servers == 0,
+    block_servers % 128 == 0 and B % block_tasks == 0 (ops.fleet_route
+    pads; padding servers carry
     pad ancestor ids that collide only with each other, so they land on
     the masked remote tier and never win).  Returns (server, tier, score),
     each (B,).
@@ -169,6 +218,8 @@ def fleet_route_pallas(q: jnp.ndarray, serving: jnp.ndarray,
         in_specs=[per_server(k), per_server(k), per_server(1),
                   per_server(depth), per_task(tasks.shape[1])],
         out_specs=[per_task(1)] * 3,
+        scratch_shapes=[pltpu.VMEM((block_tasks, 128), jnp.float32)]
+        + [pltpu.VMEM((block_tasks, 128), jnp.int32)] * max(depth, 1),
         out_shape=[
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, 1), jnp.int32),

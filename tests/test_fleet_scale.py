@@ -45,11 +45,28 @@ TOPOS = (
 )
 
 
-def _fuzz_state(rng, m, k, batch=17):
-    q = jnp.asarray(rng.integers(0, 60, (m, k)), jnp.int32)
+# grids of several blocks in both directions (ops.fleet_route's blocks
+# passed smaller): racks of 6 and groups of 12 straddle the 128-server
+# block edges, the hot tasks sit on servers across one edge, and few
+# queue values make equal scores in different blocks common
+MULTI_BLOCK = (
+    (loc.Topology(1200, 6), loc.Rates()),
+    (loc.Topology(1200, (6, 12)), loc.Rates(0.5, 0.45, 0.35, 0.25)),
+)
+# (topology, rates, fuzz states, fuzz options, fleet_route block options)
+ROUTE_CASES = (
+    [(t, r, 10, {}, {}) for t, r in TOPOS]
+    + [(t, r, 3, dict(batch=300, qmax=3, hot=np.arange(122, 134)),
+        dict(block_tasks=64, block_servers=128)) for t, r in MULTI_BLOCK])
+ROUTE_IDS = ["depth0", "depth1", "depth2", "depth1_multiblock",
+             "depth2_multiblock"]
+
+
+def _fuzz_state(rng, m, k, batch=17, qmax=60, hot=np.arange(6)):
+    q = jnp.asarray(rng.integers(0, qmax, (m, k)), jnp.int32)
     serving = jnp.asarray(rng.integers(0, 8, (m,)), jnp.int32)
-    # half the batch piles onto servers 0..5 so group minima collide
-    hot = np.stack([np.sort(rng.choice(6, 3, replace=False))
+    # half the batch piles onto the hot servers so group minima collide
+    hot = np.stack([np.sort(rng.choice(hot, 3, replace=False))
                     for _ in range(batch // 2)])
     cold = np.stack([np.sort(rng.choice(m, 3, replace=False))
                      for _ in range(batch - batch // 2)])
@@ -57,31 +74,33 @@ def _fuzz_state(rng, m, k, batch=17):
     return q, serving, locs
 
 
-@pytest.mark.parametrize("topo,rates", TOPOS,
-                         ids=["depth0", "depth1", "depth2"])
-def test_fleet_route_kernel_matches_oracle(topo, rates):
+@pytest.mark.parametrize("topo,rates,states,fuzz,blocks", ROUTE_CASES,
+                         ids=ROUTE_IDS)
+def test_fleet_route_kernel_matches_oracle(topo, rates, states, fuzz,
+                                           blocks):
     rng = np.random.default_rng(0)
     m = topo.num_servers
     ctx = make_ctx(topo)
     est = loc.per_server_rates(rates.as_array(), m)
-    for _ in range(10):
-        q, serving, locs = _fuzz_state(rng, m, est.shape[1])
-        sk, tk, vk = kops.fleet_route(q, serving, est, ctx.anc, locs)
+    for _ in range(states):
+        q, serving, locs = _fuzz_state(rng, m, est.shape[1], **fuzz)
+        sk, tk, vk = kops.fleet_route(q, serving, est, ctx.anc, locs,
+                                      **blocks)
         sr, tr, vr = ref.fleet_route(q, serving, est, ctx.anc, locs)
         np.testing.assert_array_equal(sk, sr)
         np.testing.assert_array_equal(tk, tr)
         np.testing.assert_array_equal(vk, vr)
 
 
-@pytest.mark.parametrize("topo,rates", TOPOS,
-                         ids=["depth0", "depth1", "depth2"])
-def test_segmin_route_matches_oracle(topo, rates):
+@pytest.mark.parametrize("topo,rates,states,fuzz",
+                         [c[:4] for c in ROUTE_CASES], ids=ROUTE_IDS)
+def test_segmin_route_matches_oracle(topo, rates, states, fuzz):
     rng = np.random.default_rng(1)
     m = topo.num_servers
     ctx = make_ctx(topo)
     est = loc.per_server_rates(rates.as_array(), m)
-    for _ in range(10):
-        q, serving, locs = _fuzz_state(rng, m, est.shape[1])
+    for _ in range(states):
+        q, serving, locs = _fuzz_state(rng, m, est.shape[1], **fuzz)
         w = bp.workload(bp.PandasState(q=q, serving=serving), est)
         si, ti, vi = _private_route_segmin(w, est, ctx, locs)
         sr, tr, vr = ref.fleet_route(q, serving, est, ctx.anc, locs)
