@@ -1,6 +1,7 @@
-"""Benchmark orchestrator: one section per paper table/figure plus kernel,
-serving, and roofline benches.  Prints ``name,us_per_call,derived`` CSV and
-writes figure data to experiments/figures/*.csv.
+"""Study orchestrator: one section per paper figure plus the serving
+studies.  Prints ``name,us_per_call,derived`` CSV and writes figure data to
+experiments/figures/*.csv.  Speed is measured on the chip by `bench/run.py`,
+not here.
 
     PYTHONPATH=src python -m benchmarks.run [--full]
 
@@ -54,15 +55,8 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-scale horizons (slow on 1 CPU core)")
     ap.add_argument("--only", default=None,
-                    help="comma list: fig1,fig2,fig34,fig56,drift,kernels,"
-                         "sim_throughput,scaling,placement,replication,"
-                         "control,serving,serving_scenarios,serving_control,"
-                         "trace_replay,roofline")
-    ap.add_argument("--json", default=None, metavar="PATH",
-                    help="additionally write every bench row as a "
-                         "machine-readable JSON perf record (the artifact "
-                         "CI uploads, e.g. BENCH_sim.json; schema 2: "
-                         "records + per-section wall times)")
+                    help="comma list: fig1,fig2,fig34,fig56,drift,serving,"
+                         "serving_scenarios,serving_control,trace_replay")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a Chrome trace-event JSON of the run "
                          "(section spans, engine route/admit/decode "
@@ -76,8 +70,7 @@ def main() -> None:
     print(f"# persistent compilation cache: {enable_persistent_cache()}",
           file=sys.stderr)
 
-    from benchmarks import bench_kernels, bench_roofline, bench_serving
-    from benchmarks import bench_sim, figures
+    from benchmarks import bench_serving, figures
 
     tracer = None
     if args.trace:
@@ -89,7 +82,6 @@ def main() -> None:
     outdir.mkdir(parents=True, exist_ok=True)
     csv_rows = []
     fig_rows = []
-    section_times = {}
 
     def section(name, fn):
         if only and name not in only:
@@ -100,9 +92,7 @@ def main() -> None:
         else:
             with tracer.span(f"section:{name}", cat="section"):
                 rows = fn()
-        dt = time.time() - t0
-        section_times[name] = dt
-        print(f"# {name} ({dt:.1f}s)", file=sys.stderr)
+        print(f"# {name} ({time.time() - t0:.1f}s)", file=sys.stderr)
         if rows and isinstance(rows[0], dict):
             fig_rows.extend(rows)
             # summarize per figure/algo: worst-case delay
@@ -122,19 +112,12 @@ def main() -> None:
     section("fig34", lambda: figures.fig34_under(fast))
     section("fig56", lambda: figures.fig56_over(fast))
     section("drift", lambda: figures.fig_drift(fast))
-    section("kernels", lambda: bench_kernels.bench(fast))
-    section("sim_throughput", lambda: bench_sim.bench(fast))
-    section("scaling", lambda: bench_sim.bench_scaling(fast))
-    section("placement", lambda: bench_sim.bench_placement(fast))
-    section("replication", lambda: bench_sim.bench_replication(fast))
-    section("control", lambda: bench_sim.bench_control(fast))
     section("serving", lambda: bench_serving.bench(fast, tracer=tracer))
     section("serving_scenarios", lambda: bench_serving.bench_scenarios(fast))
     section("serving_control",
             lambda: bench_serving.bench_control(fast, tracer=tracer))
     section("trace_replay", lambda: bench_serving.replay_trace(
         fast=fast, export_path="experiments/traces/replayed.jsonl"))
-    section("roofline", lambda: bench_roofline.bench(fast))
 
     if fig_rows:
         keys = sorted({k for r in fig_rows for k in r})
@@ -147,28 +130,6 @@ def main() -> None:
             csv_rows.append((f"claim_{k}", 1.0 if v else 0.0, str(v)))
         print(f"# wrote {outdir / 'figures.csv'} ({len(fig_rows)} rows); "
               f"claims: {claims}", file=sys.stderr)
-
-    if args.json:
-        import json
-        import platform
-        record = {
-            # schema 2: adds "sections" (per-section wall seconds) and the
-            # sim_compile_sec_* rows split out of the throughput numbers
-            "schema": 2,
-            "suite": "benchmarks.run",
-            "full": bool(args.full),
-            "python": platform.python_version(),
-            "machine": platform.machine(),
-            "sections": {k: round(v, 3) for k, v in section_times.items()},
-            "records": [{"name": name, "value": float(val),
-                         "derived": str(derived)}
-                        for name, val, derived in csv_rows],
-        }
-        with open(args.json, "w") as f:
-            json.dump(record, f, indent=2, sort_keys=True)
-            f.write("\n")
-        print(f"# wrote {args.json} ({len(csv_rows)} records)",
-              file=sys.stderr)
 
     if tracer is not None:
         tracer.save(args.trace)

@@ -10,7 +10,7 @@ benches call, on `jax.devices()[0]`, in this one process:
               paper scale (Topology(24, 6), p_hot 0.5): 3 loads x 2
               network-error settings x 2 seeds, horizon 2000;
   (b) fleet   `simulator.simulate` and `sweep(fleet=True)` of
-              balanced_pandas at M=10008 with the scaling bench's arrival
+              balanced_pandas at M=10008 with the fleet cells' arrival
               batch (2.05 x 0.8 x capacity), horizon 512 — the fleet
               backend with the compiled Pallas route kernel in its chunk;
   (c) serving `ServingEngine` drains of 8 requests behind the
@@ -26,8 +26,9 @@ matches offered load (every load here is below capacity); every metric is
 finite; dense and fleet results agree with the same calls on the host CPU
 device within `DENSE_BAND` and `FLEET_BAND` (see there); the fleet
 sweep's cell equals `simulate` at the same load and seed.  Each phase
-prints its wall time, with compilation reported as set-up.  The last line
-of standard output is one JSON object naming the device.
+prints its wall time (compilation included; `bench/run.py` measures speed
+and set-up).  The last line of standard output is one JSON object naming
+the device.
 """
 
 from __future__ import annotations
@@ -68,36 +69,6 @@ def check(ok: bool, what: str) -> None:
         raise SmokeFailure(what)
 
 
-class CompileClock:
-    """Seconds JAX spent lowering, compiling and reading the persistent
-    cache, from its own monitoring events (tracing is left out: its events
-    nest, one per inner jit, and would be counted twice)."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration",
-              "/jax/compilation_cache/cache_retrieval_time_sec")
-
-    def __init__(self):
-        import jax
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event in self.EVENTS:
-            self.total += duration
-
-
-def timed(clock: CompileClock, label: str, fn, *args, **kw):
-    import jax
-    c0, t0 = clock.total, time.perf_counter()
-    out = jax.block_until_ready(fn(*args, **kw))
-    wall = time.perf_counter() - t0
-    setup = clock.total - c0
-    print(f"  {label}: wall {wall:.3f} s = set-up (compile) "
-          f"{setup:.3f} s + run {wall - setup:.3f} s", flush=True)
-    return out
-
-
 def compare_to_cpu(label, chip, cpu, band):
     """Print chip vs host per metric; hold each to its band."""
     import numpy as np
@@ -125,7 +96,7 @@ def check_metrics(label, out, lam) -> None:
           f"{rel:.3g} <= {THROUGHPUT_BAND})")
 
 
-def phase_dense(clock, cpu, m: int = 24, horizon: int = 2000):
+def phase_dense(cpu, m: int = 24, horizon: int = 2000):
     import jax
     import numpy as np
     from repro.core import locality as loc, simulator as sim
@@ -138,12 +109,11 @@ def phase_dense(clock, cpu, m: int = 24, horizon: int = 2000):
                      for eps, sign in ERRORS])
     seeds = np.asarray(SEEDS)
     for pol in POLICIES:
-        out = timed(clock, f"dense sweep {pol} chip", sim.sweep, pol, cfg,
-                    lam, ests, seeds)
+        out = jax.block_until_ready(sim.sweep(pol, cfg, lam, ests, seeds))
         check_metrics(f"dense {pol}", out, lam[:, None, None])
         with jax.default_device(cpu):
-            ref = timed(clock, f"dense sweep {pol} cpu", sim.sweep, pol,
-                        cfg, lam, ests, seeds)
+            ref = jax.block_until_ready(
+                sim.sweep(pol, cfg, lam, ests, seeds))
         compare_to_cpu(f"dense {pol}", out, ref, DENSE_BAND)
 
 
@@ -159,7 +129,7 @@ def fleet_snapshots(rng, m, k, batch, hot_rack):
     return q, serving, locs
 
 
-def phase_fleet(clock, cpu, m: int = FLEET_M, horizon: int = 512):
+def phase_fleet(cpu, m: int = FLEET_M, horizon: int = 512):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -183,10 +153,10 @@ def phase_fleet(clock, cpu, m: int = FLEET_M, horizon: int = 512):
     for i in range(3):
         q, serving, locs = fleet_snapshots(rng, m, topo.num_tiers,
                                            cfg.max_arrivals, ctx.hot_rack_size)
-        sk, tk, vk = timed(clock, f"route kernel snapshot {i}", route, q,
-                           serving, est, ctx.anc, locs)
-        sr, tr, vr = timed(clock, f"ref.fleet_route snapshot {i}", oracle, q,
-                           serving, est, ctx.anc, locs)
+        sk, tk, vk = jax.block_until_ready(
+            route(q, serving, est, ctx.anc, locs))
+        sr, tr, vr = jax.block_until_ready(
+            oracle(q, serving, est, ctx.anc, locs))
         check(np.array_equal(sk, sr) and np.array_equal(tk, tr),
               f"kernel server and tier equal ref.fleet_route (snapshot {i}, "
               f"B={len(locs)}, M={m})")
@@ -194,51 +164,50 @@ def phase_fleet(clock, cpu, m: int = FLEET_M, horizon: int = 512):
 
     fc = fleet_sim.FleetConfig()
     init, chunk = fleet_sim._jitted_chunk("balanced_pandas", cfg, fc)
-    compiled = timed(clock, "fleet chunk compile", lambda: chunk.lower(
+    compiled = chunk.lower(
         init(), jnp.int32(0), jnp.float32(lam),
-        jnp.asarray(est, jnp.float32), jnp.uint32(0)).compile())
+        jnp.asarray(est, jnp.float32), jnp.uint32(0)).compile()
     text = compiled.as_text()
     check("tpu_custom_call" in text,
           "compiled fleet chunk contains the route kernel")
 
-    out = timed(clock, "fleet simulate (kernel) chip", sim.simulate,
-                "balanced_pandas", cfg, lam, est, seed=0)
+    out = jax.block_until_ready(
+        sim.simulate("balanced_pandas", cfg, lam, est, seed=0))
     check_metrics("fleet simulate", out, lam)
-    segmin = timed(clock, "fleet simulate (segment-min) chip", sim.simulate,
-                   "balanced_pandas", cfg, lam, est, seed=0,
-                   fleet=fleet_sim.FleetConfig(use_pallas=False))
+    segmin = jax.block_until_ready(sim.simulate(
+        "balanced_pandas", cfg, lam, est, seed=0,
+        fleet=fleet_sim.FleetConfig(use_pallas=False)))
     check(out == segmin, "fleet metrics with the kernel equal the "
           "segment-min route's (bitwise)")
 
     lam_grid = np.asarray((0.6 * cap, lam), np.float32)
     ests, seeds = est[None], np.asarray(SEEDS)
-    grid = timed(clock, "fleet sweep (kernel) chip", sim.sweep,
-                 "balanced_pandas", cfg, lam_grid, ests, seeds, fleet=True)
+    grid = jax.block_until_ready(sim.sweep(
+        "balanced_pandas", cfg, lam_grid, ests, seeds, fleet=True))
     check_metrics("fleet sweep", grid, lam_grid[:, None, None])
     check(all(float(grid[k][1, 0, 0]) == v for k, v in out.items()),
           "fleet sweep at (0.8 capacity, seed 0) equals simulate (bitwise)")
 
     host = fleet_sim.FleetConfig(use_pallas=False)
     with jax.default_device(cpu):
-        out_cpu = timed(clock, "fleet simulate cpu", sim.simulate,
-                        "balanced_pandas", cfg, lam, est, seed=0, fleet=host)
-        grid_cpu = timed(clock, "fleet sweep cpu", sim.sweep,
-                         "balanced_pandas", cfg, lam_grid, ests, seeds,
-                         fleet=host)
+        out_cpu = jax.block_until_ready(sim.simulate(
+            "balanced_pandas", cfg, lam, est, seed=0, fleet=host))
+        grid_cpu = jax.block_until_ready(sim.sweep(
+            "balanced_pandas", cfg, lam_grid, ests, seeds, fleet=host))
     compare_to_cpu("fleet simulate", out, out_cpu, FLEET_BAND)
     compare_to_cpu("fleet sweep", grid, grid_cpu, FLEET_BAND)
 
 
-def phase_serving(clock, arch: str = "granite_moe_1b", n: int = 8):
+def phase_serving(arch: str = "granite_moe_1b", n: int = 8):
+    import jax
     import numpy as np
     from repro.launch.serve import build_engine, make_requests
 
     print(f"  model: {arch} smoke config — the engine's stand-in model, "
           f"not its published widths", flush=True)
-    cfg, eng = timed(clock, "engine build", build_engine, arch,
-                     "balanced_pandas")
+    cfg, eng = jax.block_until_ready(build_engine(arch, "balanced_pandas"))
     reqs = make_requests(cfg, n)
-    out = timed(clock, f"drain {n} requests", eng.run_until_drained, reqs)
+    out = jax.block_until_ready(eng.run_until_drained(reqs))
     check(len(out) == n, f"all {n} requests drained")
     for r in out:
         toks = np.asarray(r.generated)
@@ -272,16 +241,14 @@ def main() -> int:
     print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}; "
           f"jax {jax.__version__}; compile cache "
           f"{enable_persistent_cache()}", flush=True)
-    clock = CompileClock()
-    for name, phase in (("a dense", lambda: phase_dense(clock, cpu)),
-                        ("b fleet", lambda: phase_fleet(clock, cpu)),
-                        ("c serving", lambda: phase_serving(clock))):
+    for name, phase in (("a dense", lambda: phase_dense(cpu)),
+                        ("b fleet", lambda: phase_fleet(cpu)),
+                        ("c serving", phase_serving)):
         print(f"phase ({name})", flush=True)
-        c0, t0 = clock.total, time.perf_counter()
+        t0 = time.perf_counter()
         phase()
-        wall = time.perf_counter() - t0
-        print(f"phase ({name}) done: wall {wall:.3f} s, of which set-up "
-              f"(compile) {clock.total - c0:.3f} s", flush=True)
+        print(f"phase ({name}) done: wall {time.perf_counter() - t0:.3f} s",
+              flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

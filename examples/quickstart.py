@@ -52,14 +52,15 @@ def main() -> None:
     from repro.kernels import ops, ref
     rng = np.random.default_rng(0)
     m, b = 1024, 128
-    wl = jnp.asarray(rng.uniform(0, 50, m), jnp.float32)
+    q = jnp.asarray(rng.integers(0, 20, (m, 3)), jnp.int32)
+    serving = jnp.asarray(rng.integers(0, 4, m), jnp.int32)
     er = jnp.asarray(np.tile([0.5, 0.45, 0.25], (m, 1)), jnp.float32)
     sr = jnp.asarray(np.arange(m) // 32, jnp.int32)
     tl = jnp.sort(jnp.asarray(rng.integers(0, m, (b, 3)), jnp.int32), 1)
-    s_k, t_k, _ = ops.wwl_route(wl, er, sr, tl)
-    s_r, t_r, _ = ref.wwl_route(wl, er, sr, tl)
+    s_k, t_k, _ = ops.fleet_route(q, serving, er, sr, tl)
+    s_r, t_r, _ = ref.fleet_route(q, serving, er, sr, tl)
     assert (np.asarray(s_k) == np.asarray(s_r)).all()
-    print(f"== kernel: wwl_route({b} tasks x {m} servers) matches oracle; "
+    print(f"== kernel: fleet_route({b} tasks x {m} servers) matches oracle; "
           f"locality mix {np.bincount(np.asarray(t_k), minlength=3)} ==")
 
     # --- 3. training through the locality-aware pipeline --------------------

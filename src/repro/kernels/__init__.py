@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the perf-critical compute paths:
 
-  wwl_route        — batched Balanced-PANDAS weighted-workload argmin routing
-  slot_step        — fused fleet slot-step (workload + private-route argmin)
+  slot_step        — fused fleet slot-step: Balanced-PANDAS workload +
+                     private-route argmin (`fleet_route`)
   maxweight        — batched JSQ-MaxWeight weighted argmax claims
   flash_attention  — block-wise online-softmax attention (GQA/SWA/softcap)
   ssd_scan         — Mamba-2 SSD chunked scan
@@ -10,5 +10,5 @@ Public API lives in ops.py (padding + interpret fallback); oracles in ref.py.
 """
 
 from repro.kernels.ops import (  # noqa: F401
-    flash_attention, fleet_route, maxweight_claim, ssd, wwl_route,
+    flash_attention, fleet_route, maxweight_claim, ssd,
 )

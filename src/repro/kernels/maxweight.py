@@ -5,9 +5,16 @@ for ``argmax_n w(m,n) * Q_n`` where the weight depends on server/queue
 identity and the deepest hierarchy level the pair shares — derived from the
 ``(depth, .)`` ancestor tables (`Topology.ancestors`), with the depth loop
 unrolled at trace time so the K=3 instance lowers to exactly one rack
-comparison.  Same tiling/accumulator structure as wwl_route (see that
-module for the TPU-adaptation rationale), with a masked max-reduction
-instead of min and the empty-queue mask folded in.
+comparison.  The empty-queue mask is folded into the score.
+
+This is a masked reduction on the vector unit, not a matmul, so the MXU
+is idle.  The kernel tiles the (B, N) score surface in 8x128-aligned
+blocks on the grid (B/bb, N/bn), queue axis innermost, streams the queue
+axis through VMEM and keeps a running (max, argmax) per idle server in
+its output block, revisited across the inner axis (the standard Pallas
+reduction pattern).  The hierarchy level of each pair is derived on the
+fly from depth integer comparisons, so no (B, N) weight matrix is
+materialized in HBM.
 """
 
 from __future__ import annotations

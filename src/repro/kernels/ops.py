@@ -9,18 +9,14 @@ validated against ref.py.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import maxweight as _mw
-from repro.kernels import ref
 from repro.kernels import slot_step as _slot
 from repro.kernels import ssd_scan as _ssd
-from repro.kernels import wwl_route as _wwl
 
 
 def _on_tpu() -> bool:
@@ -45,38 +41,6 @@ def _dilate_depth0(est, ids):
     anc = jnp.asarray(ids, jnp.int32)[None, :]
     est = jnp.concatenate([est[:, :1], est[:, 1:2], est[:, 1:2]], axis=1)
     return anc, est
-
-
-def wwl_route(workload, est_rates, server_anc, task_locals, *,
-              block_tasks: int = 128, block_servers: int = 512,
-              interpret: bool | None = None):
-    """Batched Balanced-PANDAS routing. See ref.wwl_route for semantics.
-
-    `server_anc` is the (depth, M) `Topology.ancestors` table (a legacy
-    (M,) rack map is accepted).  Accepts arbitrary B, M; pads internally
-    (padding servers get +inf workload and rate 1 so they never win the
-    argmin; their pad ancestor ids collide only with each other).
-    """
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    b, m = task_locals.shape[0], workload.shape[0]
-    anc = jnp.asarray(server_anc, jnp.int32)
-    anc = anc[None, :] if anc.ndim == 1 else anc
-    er = jnp.asarray(est_rates, jnp.float32)
-    k2 = anc.shape[0] == 0
-    if k2:
-        anc, er = _dilate_depth0(er, jnp.arange(m))
-    bs = min(block_servers, _round_up(m, 128))
-    bt = min(block_tasks, _round_up(b, 8))
-    wl = _pad_to(jnp.asarray(workload, jnp.float32), bs, 0, np.float32(3e38))
-    er = _pad_to(er, bs, 0, 1.0)
-    sa = _pad_to(anc, bs, 1, np.int32(2**30))
-    tl = _pad_to(jnp.asarray(task_locals, jnp.int32), bt, 0, 0)
-    server, tier, score = _wwl.wwl_route_pallas(
-        wl, er, sa, tl, block_tasks=bt, block_servers=bs, interpret=interpret)
-    server, tier, score = server[:b], tier[:b], score[:b]
-    if k2:
-        tier = jnp.minimum(tier, 1)  # collapse the synthetic level
-    return server, tier, score
 
 
 def fleet_route(q, serving, est_rates, server_anc, task_locals, *,
@@ -171,7 +135,3 @@ def ssd(x, a, b, c, init_state=None, *, block_t: int = 128,
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
-
-
-# Re-exported oracles for convenience in tests/benchmarks.
-reference = ref

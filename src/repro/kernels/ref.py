@@ -11,49 +11,12 @@ import jax
 import jax.numpy as jnp
 
 
-# ------------------------------------------------------------- wwl_route ---
+# ----------------------------------------------------------- fleet_route ---
 
 def _as_anc(x: jnp.ndarray) -> jnp.ndarray:
     """Normalize a legacy (M,)/(B,) rack map to a (depth, ...) table."""
     a = jnp.asarray(x)
     return a[None] if a.ndim == 1 else a
-
-
-def wwl_route(workload: jnp.ndarray, est_rates: jnp.ndarray,
-              server_anc: jnp.ndarray, task_locals: jnp.ndarray):
-    """Batched Balanced-PANDAS routing against a workload snapshot.
-
-    workload:    (M,)   f32  estimated weighted workload per server
-    est_rates:   (M,K)  f32  per-server estimated tier rates (fastest first)
-    server_anc:  (D,M)  i32  ancestor-group id per (level, server) — the
-                             `Topology.ancestors` table; a legacy (M,)
-                             rack map is accepted (D = 1, K = 3)
-    task_locals: (B,3)  i32  local servers per task
-
-    Returns (server (B,) i32, tier (B,) i32 in 0..K-1 (0 local, K-1
-    remote), score (B,) f32).  Ties break to the lowest server index
-    (deterministic; the sequential simulator keeps the paper's random
-    tie-breaking).
-    """
-    anc = _as_anc(server_anc)
-    d, m = anc.shape
-    sid = jnp.arange(m, dtype=task_locals.dtype)
-    local = jnp.any(sid[None, :, None] == task_locals[:, None, :], axis=-1)
-    tier = jnp.full(local.shape, d + 1, jnp.int32)
-    rate = jnp.broadcast_to(est_rates[None, :, d + 1], local.shape)
-    for lvl in range(d - 1, -1, -1):
-        row = anc[lvl]
-        task_groups = row[task_locals]  # (B, 3)
-        share = jnp.any(row[None, :, None] == task_groups[:, None, :],
-                        axis=-1)
-        tier = jnp.where(share, lvl + 1, tier)
-        rate = jnp.where(share, est_rates[None, :, lvl + 1], rate)
-    tier = jnp.where(local, 0, tier)
-    rate = jnp.where(local, est_rates[None, :, 0], rate)
-    score = workload[None, :] / rate  # (B, M)
-    server = jnp.argmin(score, axis=1).astype(jnp.int32)
-    b = jnp.arange(task_locals.shape[0])
-    return server, tier[b, server], score[b, server]
 
 
 def fleet_route(q: jnp.ndarray, serving: jnp.ndarray, est_rates: jnp.ndarray,

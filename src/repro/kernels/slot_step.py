@@ -29,18 +29,16 @@ scratch across the server axis.  The cross-lane minima, the tier at the
 argmin and every (bt, 1) column run once per task block, at its last
 server block: on the chip those per-row operations cost a grid step more
 than the surface does.  The kernel body holds no gather.  Per-task
-results are (B, 1) columns.  Compare `wwl_route.py`, which scores ALL
-servers (including the remote tier) against a precomputed workload
-vector: the fused kernel reads the raw policy state (q, serving)
-instead, and masks the remote tier out, because the fleet path assigns
-remote traffic by water-filling rather than per-task argmin (B tasks
-hitting the same remote argmin would pile onto one server — see
-docs/scaling.md).
+results are (B, 1) columns.  The kernel reads the raw policy state
+(q, serving) rather than a precomputed workload vector, and masks the
+remote tier out, because the fleet path assigns remote traffic by
+water-filling rather than per-task argmin (B tasks hitting the same
+remote argmin would pile onto one server — see docs/scaling.md).
 
 The ``- rate * 1e-6`` term is the same infinitesimal faster-tier
 preference the sequential simulator applies on exact workload ties
 (`core/balanced_pandas.route_one`); tie-breaking among equal scores is
-lowest-server-index (deterministic), as in the other scheduling kernels.
+lowest-server-index (deterministic), as in `maxweight.py`.
 
 Semantics contract: `ref.fleet_route`.  `sharding/sim.py` runs this
 kernel on TPU and an XLA segment-min realization elsewhere; both are
@@ -58,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LARGE = 3.0e38  # +inf surrogate inside min-accumulators (matches wwl_route)
+LARGE = 3.0e38  # +inf surrogate inside min-accumulators
 
 
 def _fleet_route_kernel(q_ref, rates_ref, serving_ref, anc_ref, tasks_ref,

@@ -66,7 +66,7 @@ def _shape(x, sharding):
 
 
 def _fleet_cfg(topo, rates, horizon=512):
-    """The scaling bench's fleet arm: λ = 0.8·capacity, batch 2.05·λ."""
+    """λ = 0.8·capacity, batch 2.05·λ: the fleet cells' arrival rule."""
     lam = 0.8 * loc.capacity_hot_rack(topo, rates, 0.5)
     cfg = sim.SimConfig(topo=topo, true_rates=rates, p_hot=0.5,
                         max_arrivals=int(2.05 * lam), horizon=horizon,

@@ -327,7 +327,7 @@ def test_placement_runs_through_simulate_and_sweep(topo, rates, name):
 
 @pytest.mark.parametrize("name", NONDEFAULT)
 def test_placement_types_feed_both_kernels(name):
-    """The sampled task_locals drive wwl_route and maxweight_claim
+    """The sampled task_locals drive fleet_route and maxweight_claim
     unchanged (kernel vs oracle on placement-sampled types)."""
     from repro.kernels import ops, ref
     topo = loc.Topology(24, (4, 12))
@@ -337,10 +337,11 @@ def test_placement_types_feed_both_kernels(name):
         jax.random.PRNGKey(0), jnp.float32(0.5), jnp.int32(0), 9), jnp.int32)
     rng = np.random.default_rng(3)
     m, b = 24, 9
-    wlv = jnp.asarray(rng.uniform(0, 50, m), jnp.float32)
+    qs = jnp.asarray(rng.integers(0, 20, (m, k)), jnp.int32)
+    serving = jnp.asarray(rng.integers(0, k + 1, m), jnp.int32)
     er = jnp.asarray(np.tile([0.5, 0.45, 0.35, 0.25], (m, 1)), jnp.float32)
-    s1, t1, sc1 = ops.wwl_route(wlv, er, anc, tl)
-    s2, t2, sc2 = ref.wwl_route(wlv, er, anc, tl)
+    s1, t1, sc1 = ops.fleet_route(qs, serving, er, anc, tl)
+    s2, t2, sc2 = ref.fleet_route(qs, serving, er, anc, tl)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
     q = jnp.asarray(rng.integers(0, 5, m), jnp.float32)

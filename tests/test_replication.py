@@ -283,7 +283,7 @@ def test_host_state_round_trip_is_json_safe():
 
 
 def test_post_migration_placements_feed_both_kernels():
-    """Post-repair replica rows drive wwl_route / maxweight_claim
+    """Post-repair replica rows drive fleet_route / maxweight_claim
     unchanged (kernel vs oracle on lifecycle-produced task_locals)."""
     from repro.kernels import ops, ref
     from repro.placement import make_placement
@@ -301,10 +301,11 @@ def test_post_migration_placements_feed_both_kernels():
     anc = jnp.asarray(topo.ancestors, jnp.int32)
     rng = np.random.default_rng(3)
     m, b = 24, 9
-    wlv = jnp.asarray(rng.uniform(0, 50, m), jnp.float32)
+    qs = jnp.asarray(rng.integers(0, 20, (m, 4)), jnp.int32)
+    serving = jnp.asarray(rng.integers(0, 5, m), jnp.int32)
     er = jnp.asarray(np.tile([0.5, 0.45, 0.35, 0.25], (m, 1)), jnp.float32)
-    s1, t1, _ = ops.wwl_route(wlv, er, anc, tl)
-    s2, t2, _ = ref.wwl_route(wlv, er, anc, tl)
+    s1, t1, _ = ops.fleet_route(qs, serving, er, anc, tl)
+    s2, t2, _ = ref.fleet_route(qs, serving, er, anc, tl)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
     q = jnp.asarray(rng.integers(0, 5, m), jnp.float32)

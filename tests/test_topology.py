@@ -278,14 +278,15 @@ def test_kernels_match_oracle_on_k_tier_ancestors(spec, rates):
     k = topo.num_tiers
     rng = np.random.default_rng(k)
     m, b = 24, 9
-    wlv = jnp.asarray(rng.uniform(0, 50, m), jnp.float32)
+    qs = jnp.asarray(rng.integers(0, 20, (m, k)), jnp.int32)
+    serving = jnp.asarray(rng.integers(0, k + 1, m), jnp.int32)
     er = jnp.asarray(np.tile(np.asarray(rates, np.float32), (m, 1))
                      * rng.uniform(0.8, 1.2, (m, k)), jnp.float32)
     tl = jnp.sort(jnp.asarray(
         np.stack([rng.choice(m, 3, replace=False) for _ in range(b)]),
         jnp.int32), axis=1)
-    s1, t1, sc1 = ops.wwl_route(wlv, er, anc, tl)
-    s2, t2, sc2 = ref.wwl_route(wlv, er, anc, tl)
+    s1, t1, sc1 = ops.fleet_route(qs, serving, er, anc, tl)
+    s2, t2, sc2 = ref.fleet_route(qs, serving, er, anc, tl)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
     np.testing.assert_allclose(np.asarray(sc1), np.asarray(sc2), rtol=1e-6)
@@ -302,21 +303,23 @@ def test_kernels_match_oracle_on_k_tier_ancestors(spec, rates):
 
 def test_k4_kernel_tier_derivation_spot_check():
     """The kernel's tier derivation weighs W/rate with the pod level in
-    between rack and remote: a lightly-loaded rack-mate (W/0.45) must beat
-    a pod-mate (W/0.35) and a remote server (W/0.25) at workloads chosen so
-    only the tier rates discriminate."""
+    between rack and remote: a rack-mate (W/0.45) must beat a pod-mate
+    (W/0.35) and a remote server (W/0.25) at equal queues, so only the tier
+    rates discriminate."""
     from repro.kernels import ops
     anc = jnp.asarray(TOPO4.ancestors, jnp.int32)
     # task locals fill rack 0 (servers 0,2,3); server 1 is the rack-mate,
-    # 5 sits in the same pod, 13 in the other pod
-    wlv = jnp.full((24,), 10.0).at[1].set(0.045).at[5].set(0.07) \
-                               .at[13].set(0.05)
+    # 5 sits in the same pod, 13 in the other pod; each of them holds one
+    # local task (W = 1/0.5 = 2), every other server ten (W = 20)
+    qs = jnp.zeros((24, 4), jnp.int32).at[:, 0].set(10)
+    qs = qs.at[jnp.asarray([1, 5, 13]), 0].set(1)
     er = jnp.tile(RATES4.as_array()[None], (24, 1))
     tl = jnp.asarray([[0, 2, 3]], jnp.int32)
-    server, tier, score = ops.wwl_route(wlv, er, anc, tl)
-    # scores: 1 -> .045/.45 = .10; 5 -> .07/.35 = .20; 13 -> .05/.25 = .20
+    server, tier, score = ops.fleet_route(qs, jnp.zeros((24,), jnp.int32),
+                                          er, anc, tl)
+    # scores: 1 -> 2/.45 = 4.44; 5 -> 2/.35 = 5.71; 13 -> 2/.25 = 8 (remote)
     assert int(server[0]) == 1 and int(tier[0]) == 1
-    assert float(score[0]) == pytest.approx(0.1)
+    assert float(score[0]) == pytest.approx(2 / 0.45)
 
 
 # ---------------------------------------------- per-rack arrival weights ---
